@@ -4,7 +4,7 @@
 PYTHON ?= python
 
 .PHONY: test test-fast bench bench-json bench-edge bench-serve quickstart \
-	docs-check shim-check bench-diff trace-check fuzz-kernels
+	docs-check bench-diff trace-check fuzz-kernels
 
 test:
 	$(PYTHON) -m pytest -q
@@ -37,11 +37,6 @@ quickstart:
 # Verify every relative link in README.md and docs/*.md resolves.
 docs-check:
 	$(PYTHON) tools/check_doc_links.py
-
-# Verify version-drifting JAX spellings (shard_map / AxisType /
-# CompilerParams) stay inside their shim modules.
-shim-check:
-	$(PYTHON) tools/check_api_shims.py
 
 # Compare freshly regenerated BENCH_*.json against the committed
 # snapshots (deterministic leaves exact, wall-clock within a band).

@@ -8,14 +8,16 @@ volume drops to O(N m^2/t^2).
 
 This benchmark compiles the shard_map Phase-2 program in all three
 modes on an 8-device worker mesh and counts wire bytes from the HLO.
-Run in a subprocess so the parent keeps 1 device:
+Run in a CPU subprocess (``JAX_PLATFORMS=cpu``) so the parent keeps
+1 device and never shares an accelerator with the child:
 
-    XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python -m benchmarks.cmpc_comm
 """
 from __future__ import annotations
 
 import json
+import os
 import subprocess
 import sys
 
@@ -39,7 +41,8 @@ fa = proto.share_a(plan, A, rng); fb = proto.share_b(plan, B, rng)
 noise = f.random(rng, (plan.n_workers, plan.scheme.z, m//2, m//2))
 want = f.matmul(A.T, B)
 
-out = {"n_workers": plan.n_workers, "n_total": plan.n_total,
+out = {"platform": jax.devices()[0].platform,
+       "n_workers": plan.n_workers, "n_total": plan.n_total,
        "paper_zeta_scalars": plan.n_workers*(plan.n_workers-1)*(m//2)*(m//2)}
 for mode in ("all_to_all", "psum", "psum_scatter"):
     compiled = run_phase2_sharded(plan, fa, fb, noise, mesh, mode=mode,
@@ -57,8 +60,14 @@ def run():
     res = subprocess.run(
         [sys.executable, "-c", _CHILD],
         capture_output=True, text=True, timeout=580,
-        env={"XLA_FLAGS": "--xla_force_host_platform_device_count=8",
-             "PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root"},
+        # a CPU rehearsal: the child never reaches for the accelerator
+        # its parent may hold
+        env=dict(
+            os.environ,
+            XLA_FLAGS="--xla_force_host_platform_device_count=8",
+            JAX_PLATFORMS="cpu",
+            PYTHONPATH="src",
+        ),
     )
     if res.returncode != 0:
         raise RuntimeError(res.stdout + res.stderr)
@@ -80,7 +89,8 @@ def run():
             "name": "cmpc_phase2_collectives",
             "us_per_call": 0,
             "derived": (
-                f"csv={path} N={data['n_workers']} all_to_all={a2a} psum={ps} "
+                f"csv={path} platform={data['platform']} "
+                f"N={data['n_workers']} all_to_all={a2a} psum={ps} "
                 f"reduce_scatter={rs} saving={a2a / max(rs, 1):.1f}x all_correct="
                 f"{all(data[m]['correct'] for m in ('all_to_all','psum','psum_scatter'))}"
             ),

@@ -38,8 +38,14 @@ def run_sharded_child(module: str, devices: int, timeout: int = 900) -> Dict:
     A subprocess on purpose: ``--xla_force_host_platform_device_count``
     must be set before JAX initializes, and forcing a device split in
     the parent would perturb its single-device benchmark numbers.
+
+    The child is a CPU rehearsal and runs with ``JAX_PLATFORMS=cpu``
+    whatever the parent has: a parent that has touched JAX holds the
+    accelerator, and a child reaching for it would fail or hang.  Its
+    report carries ``platform`` so nobody reads it as a device number.
     """
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     # append (not overwrite): any operator-supplied XLA_FLAGS must apply
     # to the child too, or its numbers aren't comparable to the parent's
     flags = f"--xla_force_host_platform_device_count={devices}"

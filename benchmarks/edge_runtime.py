@@ -580,6 +580,7 @@ def _sharded_child():
     latency = ShiftedExponential(shift=1.0, scale=1.0)
     faults = FaultSpec(straggler_frac=0.2, straggler_slowdown=10.0)
     out = {
+        "platform": jax.devices()[0].platform,
         "devices": len(jax.devices()),
         "batch": BATCH_REPLAY,
         "mode": "all_to_all",

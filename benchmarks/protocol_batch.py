@@ -102,6 +102,7 @@ def _sharded_child():
         / SHARDED_BATCH
     )
     out = {
+        "platform": jax.devices()[0].platform,
         "devices": len(jax.devices()),
         "batch": SHARDED_BATCH,
         "n_workers": plan.n_workers,

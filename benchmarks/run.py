@@ -15,6 +15,10 @@ def main() -> None:
     ap.add_argument("--only", default="")
     args = ap.parse_args()
 
+    from repro.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
+
     from . import (
         cmpc_comm,
         edge_runtime,

@@ -34,26 +34,25 @@ partial values before reducing mod p, so the requirement is
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
-
-from ..compat import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..kernels.modmatmul.ops import mod_matmul
 from .planner import CMPCPlan
 
 
-def _pad_to_multiple(x: np.ndarray, mult: int, axis: int = 0) -> np.ndarray:
-    pad = (-x.shape[axis]) % mult
-    if pad == 0:
-        return x
-    widths = [(0, 0)] * x.ndim
-    widths[axis] = (0, pad)
-    return np.pad(x, widths)
+@functools.partial(jax.jit, static_argnames=("npad",))
+def _worker_major(x: jnp.ndarray, npad: int) -> jnp.ndarray:
+    """[batch, n_total, ...] -> [npad, batch, ...] on the shares' own
+    device: the worker axis leads and pad workers hold zeros."""
+    x = jnp.moveaxis(x, 1, 0)
+    widths = [(0, npad - x.shape[0])] + [(0, 0)] * (x.ndim - 1)
+    return jnp.pad(x, widths)
 
 
 def run_phase2_sharded(
@@ -89,6 +88,11 @@ def run_phase2_sharded(
     (``auto``/``pallas``/``f32limb``): the per-shard worker multiply is
     a batched mod_matmul, so on TPU it lowers to one Pallas launch per
     shard with the local worker count on the batch grid axis.
+
+    The shares stay on the device: they are laid out worker-major where
+    they were computed and then sent shard by shard to the mesh.  The
+    jitted exchange program is cached per (mesh, mode, shapes), so
+    replays of one shape compile once.
     """
     p = plan.field.p
     d = mesh.shape[axis]
@@ -105,22 +109,15 @@ def run_phase2_sharded(
         ids = np.asarray(worker_ids)
         mix = plan.phase2_matrix_cached(ids)
 
-    fa_np = np.asarray(fa)
-    fb_np = np.asarray(fb)
     noise_np = np.asarray(noise)
-    batched = fa_np.ndim == 4
+    batched = fa.ndim == 4
     if not batched:
-        fa_np = fa_np[None]
-        fb_np = fb_np[None]
+        fa = fa[None]
+        fb = fb[None]
         noise_np = noise_np[None]
-    batch = fa_np.shape[0]
+    batch = fa.shape[0]
 
-    # Worker axis leads on the mesh; the batch joins the per-worker
-    # payload.  Pad worker-stacked operands to the axis size; pad
-    # workers are receive-only (zero mix rows / zero noise).
-    fa_p = _pad_to_multiple(np.moveaxis(fa_np, 1, 0), d)  # [npad, batch, br, bk]
-    fb_p = _pad_to_multiple(np.moveaxis(fb_np, 1, 0), d)
-    assert fa_p.shape[0] == npad
+    # Pad workers are receive-only (zero mix rows / zero noise).
     mix_rows = np.zeros((npad, npad), np.int64)
     mix_rows[ids, :n_total] = mix  # [senders, receivers]
     vnz = np.zeros((npad, plan.scheme.z), np.int64)
@@ -128,20 +125,42 @@ def run_phase2_sharded(
     # noise rows follow ids order; layout [npad, z, batch, br, bc] so the
     # local reshape (nloc, z, payload) flattens batch into the payload.
     noise_w = np.moveaxis(noise_np, 0, 2)  # [n_workers, z, batch, br, bc]
-    noise_p = np.zeros((npad,) + noise_w.shape[1:], np.int64)
+    noise_p = np.zeros((npad,) + noise_w.shape[1:], np.int32)
     noise_p[ids] = noise_w
 
-    mix_j = jnp.asarray(mix_rows.astype(np.int32))
-    vn_j = jnp.asarray(vnz.astype(np.int32))
-    noise_j = jnp.asarray(noise_p.astype(np.int32))
-    fa_j = jnp.asarray(fa_p)
-    fb_j = jnp.asarray(fb_p)
+    sharded = NamedSharding(mesh, P(axis))
+    args = (
+        jax.device_put(_worker_major(jnp.asarray(fa), npad), sharded),
+        jax.device_put(_worker_major(jnp.asarray(fb), npad), sharded),
+        jax.device_put(mix_rows.astype(np.int32), sharded),
+        jax.device_put(noise_p, sharded),
+        jax.device_put(vnz.astype(np.int32), NamedSharding(mesh, P())),
+    )
+    br = fa.shape[2]
+    bc = fb.shape[3]
+    program = _phase2_program(
+        mesh, axis, mode, matmul_backend, p, plan.scheme.z, batch, br, bc
+    )
+    if return_compiled:
+        return program.lower(*args).compile()
+    i_evals = np.asarray(program(*args))
+    i_evals = np.moveaxis(i_evals[:n_total], 0, 1)  # [batch, n_total, br, bc]
+    return i_evals if batched else i_evals[0]
 
-    br = fa_p.shape[2]
-    bc = fb_p.shape[3]
+
+@functools.lru_cache(maxsize=32)
+def _phase2_program(
+    mesh: Mesh, axis: str, mode: str, matmul_backend: str,
+    p: int, z: int, batch: int, br: int, bc: int,
+):
+    """The jitted shard_map exchange for one (mesh, mode, shape)."""
+    if mode not in ("all_to_all", "psum", "psum_scatter"):
+        raise ValueError(f"unknown mode {mode}")
+    d = mesh.shape[axis]
     blk = batch * br * bc  # per-worker flat payload (whole batch)
 
-    def local(fa_l, fb_l, mix_l, noise_l):
+    def local(fa_l, fb_l, mix_l, noise_l, vn):
+        npad = vn.shape[0]
         # Phase 2a: every local worker multiplies its shares (the batch
         # is just another leading dim of the batched mod_matmul).
         h_l = mod_matmul(fa_l, fb_l, p=p, backend=matmul_backend)  # [nloc, batch, br, bc]
@@ -154,17 +173,17 @@ def run_phase2_sharded(
         ) % jnp.uint32(p)
         # Per-worker blinding: noise_eval[nl, r] = sum_w R[nl, w] vn[r, w],
         # accumulated mod p each step (uint32-safe for any z).
-        nz = noise_l.reshape(nloc, plan.scheme.z, blk)
+        nz = noise_l.reshape(nloc, z, blk)
 
         def nmix(acc, w):
             term = (
-                vn_j[:, w][None, :, None].astype(jnp.uint32)
+                vn[:, w][None, :, None].astype(jnp.uint32)
                 * nz[:, w, :][:, None, :].astype(jnp.uint32)
             ) % jnp.uint32(p)
             return (acc + term) % jnp.uint32(p), None
 
-        acc0 = jnp.zeros((nloc, vn_j.shape[0], blk), jnp.uint32)
-        noise_eval, _ = jax.lax.scan(nmix, acc0, jnp.arange(plan.scheme.z))
+        acc0 = jnp.zeros((nloc, npad, blk), jnp.uint32)
+        noise_eval, _ = jax.lax.scan(nmix, acc0, jnp.arange(z))
         contrib = ((contrib + noise_eval) % jnp.uint32(p)).astype(jnp.int32)
 
         if mode == "all_to_all":
@@ -179,27 +198,21 @@ def run_phase2_sharded(
             idx = jax.lax.axis_index(axis)
             nloc_r = npad // d
             i_local = jax.lax.dynamic_slice_in_dim(i_all, idx * nloc_r, nloc_r, 0)
-        elif mode == "psum_scatter":
+        else:  # psum_scatter
             part = _mod_sum(contrib, p)  # [npad, blk]
             i_local = jax.lax.psum_scatter(part, axis, scatter_dimension=0, tiled=True) % p
-        else:
-            raise ValueError(f"unknown mode {mode}")
         return i_local.astype(jnp.int32).reshape(-1, batch, br, bc)
 
     spec = P(axis)
-    shard_fn = shard_map(
-        local,
-        mesh=mesh,
-        in_specs=(spec, spec, spec, spec),
-        out_specs=spec,
-        check_vma=False,
+    return jax.jit(
+        jax.shard_map(
+            local,
+            mesh=mesh,
+            in_specs=(spec, spec, spec, spec, P()),
+            out_specs=spec,
+            check_vma=False,
+        )
     )
-    jitted = jax.jit(shard_fn)
-    if return_compiled:
-        return jitted.lower(fa_j, fb_j, mix_j, noise_j).compile()
-    i_evals = np.asarray(jitted(fa_j, fb_j, mix_j, noise_j))
-    i_evals = np.moveaxis(i_evals[:n_total], 0, 1)  # [batch, n_total, br, bc]
-    return i_evals if batched else i_evals[0]
 
 
 def _mod_sum(x: jnp.ndarray, p: int) -> jnp.ndarray:

@@ -89,9 +89,19 @@ class Field:
         return (np.asarray(a, np.int64) * np.asarray(b, np.int64)) % self.p
 
     def matmul(self, a, b) -> np.ndarray:
-        """Exact (mod p) matmul on the host; chunked to avoid int64 overflow."""
+        """Exact (mod p) matmul on the host; chunked to avoid int64 overflow.
+
+        ``a`` is [..., K] and ``b`` must be 2D [K, N]: the chunk loop
+        slices ``b``'s leading axis as K, so a batched ``b`` would be
+        contracted wrongly (loop over the batch instead).
+        """
         a = self.asarray(a)
         b = self.asarray(b)
+        if b.ndim != 2:
+            raise ValueError(
+                f"Field.matmul needs a 2D right-hand side [K, N], got shape "
+                f"{b.shape}; loop over a batched operand instead"
+            )
         k = a.shape[-1]
         # (p-1)^2 * chunk must stay < 2**63; p < 2**31 -> chunk >= 2 always ok.
         chunk = max(1, int((2**62) // (int(self.p - 1) ** 2)))

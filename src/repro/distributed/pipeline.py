@@ -21,8 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..compat import shard_map
-
 
 def pipeline_forward(
     stage_fn: Callable[[Any, jnp.ndarray], jnp.ndarray],
@@ -68,7 +66,7 @@ def pipeline_forward(
         (buf, outs), _ = jax.lax.scan(step, (buf, outs), jnp.arange(steps))
         return outs[None]  # [1, steps, ...] stage-local
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(axis), P(None)),

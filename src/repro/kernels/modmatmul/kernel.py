@@ -26,9 +26,10 @@ unbatched operand (e.g. a constant mixing or decode matrix against a
 batched stack) keeps its 2D shape and is indexed batch-invariantly, so
 it is never broadcast or copied per batch element.
 
-Tiles are MXU-aligned (M tiles are sublane multiples of 8, N/K tiles
-lane multiples of 128 — ``ops.pick_tiles`` chooses them from the actual
-operand shape).  The accumulator lives in the output VMEM block; the K
+Each tile is either the whole array dim (no alignment needed, nothing
+padded) or aligned: M tiles sublane multiples of 8, N/K tiles lane
+multiples of 128 — ``ops.pick_tiles`` chooses them from the actual
+operand shape.  The accumulator lives in the output VMEM block; the K
 grid axis is ``arbitrary`` (sequential) so accumulation is race-free.
 
 Two arithmetic variants share the launch/grid machinery
@@ -41,7 +42,8 @@ Two arithmetic variants share the launch/grid machinery
   K step through a pure-uint32 Barrett reduction
   (``gf.barrett_reduce_u32``); the accumulator bound widens to 2**31
   (``bk <= INT32_KERNEL_MAX_BK``), so deep contractions need no
-  K-tiling at all.
+  K-tiling at all.  Interpret mode only: the TPU v5e MXU has no
+  int32 x int32 matmul, so a compile raises ``NotImplementedError``.
 
 ``modmatmul_masked_pallas`` additionally fuses the protocol's blinding
 masks into the tile: a counter-based threefry2x32 stream (matching
@@ -66,18 +68,21 @@ from ...core.gf import (
     threefry2x32,
 )
 
-# JAX renamed pltpu.TPUCompilerParams -> pltpu.CompilerParams across
-# releases; resolve whichever this install provides.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
-
 LIMB = 256.0
 
 # Per-tile contraction bound for the native-int32 kernel: each raw
 # signed-int32 limb dot accumulates bk products of 8-bit limbs, so
 # bk * 255**2 must stay below 2**31.
 INT32_KERNEL_MAX_BK = (1 << 31) // (255 * 255)  # 33025 -> bk <= 33024 padded
+
+# The int32 variant runs only in interpret mode: Mosaic refuses its
+# limb dots on TPU v5e ("Bad lhs/rhs type: 'vector<8x128xi32>'") because
+# that MXU has no int32 x int32 matmul.
+INT32_REFUSAL = (
+    "the pallas_int32 kernel does not compile for TPU: Mosaic has no "
+    "int32 x int32 matmul on the MXU; use backend='pallas' (f32 limbs) "
+    "on the chip, or interpret=True to validate the int32 arithmetic"
+)
 
 
 def _modf32(x, p):
@@ -279,14 +284,14 @@ def _launch(kernel, grid, in_specs, o_spec, out_shape, interpret, operands):
         in_specs=list(in_specs),
         out_specs=o_spec,
         out_shape=jax.ShapeDtypeStruct(out_shape, jnp.int32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * (len(grid) - 1) + ("arbitrary",)
         ),
         interpret=interpret,
     )(*operands)
 
 
-def _base_kernel(variant: str, p: int, bk: int, k_axis: int):
+def _base_kernel(variant: str, p: int, bk: int, k_axis: int, interpret: bool):
     """The unmasked tile body for a kernel variant ("f32" | "int32")."""
     if variant == "f32":
         if bk > 256:
@@ -296,6 +301,8 @@ def _base_kernel(variant: str, p: int, bk: int, k_axis: int):
         )
     if variant != "int32":
         raise ValueError(f"unknown kernel variant {variant}")
+    if not interpret:
+        raise NotImplementedError(INT32_REFUSAL)
     if bk * 255 * 255 >= 1 << 31:
         raise ValueError(
             f"int32 kernel: bk={bk} overflows the signed-int32 limb-dot "
@@ -336,7 +343,7 @@ def modmatmul_pallas(
     grid, a_spec, b_spec, o_spec, out_shape, _, k_axis = _grid_and_specs(
         a, b, bm, bn, bk
     )
-    kernel = _base_kernel(variant, p, bk, k_axis)
+    kernel = _base_kernel(variant, p, bk, k_axis, interpret)
     return _launch(kernel, grid, [a_spec, b_spec], o_spec, out_shape, interpret, (a, b))
 
 
@@ -404,7 +411,7 @@ def modmatmul_masked_pallas(
     else:
         v_spec = pl.BlockSpec((bm, z), lambda i, j, kk: (i, 0))
         key_spec = pl.BlockSpec((1, 2), lambda i, j, kk: (0, 0))
-    base = _base_kernel(variant, p, bk, k_axis)
+    base = _base_kernel(variant, p, bk, k_axis, interpret)
     nk = grid[k_axis]
 
     def kernel(a_ref, b_ref, v_ref, key_ref, o_ref):

@@ -14,9 +14,9 @@ backend dispatch:
 * ``"pallas_int32"`` — the native-integer Pallas kernel: int32 limb
                         dots + in-tile uint32 Barrett reduction, so one
                         tile covers contraction depths the f32 kernel
-                        must chunk at 256 (targets integer-capable
-                        accelerator generations; validated everywhere
-                        via interpret mode).
+                        must chunk at 256.  Interpret mode only: a
+                        compile for TPU raises ``NotImplementedError``
+                        (the v5e MXU has no int32 x int32 matmul).
 * ``"f32limb"``      — portable jnp path with the f32 limb math (native
                         ``dot_general`` batching, see ``core.gf``),
 * ``"int32"``        — portable native-integer tier: chunk-batched limb
@@ -42,6 +42,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+from jax._src.pallas.mosaic.lowering import LoweringException
 
 from ...core.gf import (
     CHUNK_K,
@@ -63,6 +64,15 @@ from .kernel import (
 
 _PALLAS_VARIANTS = {"pallas": "f32", "pallas_int32": "int32"}
 
+# What lowering or compiling a candidate tiling raises when the tiling,
+# the backend or the chip's compiler refuses it (autotune skips these).
+_COMPILE_REFUSALS = (
+    ValueError,
+    NotImplementedError,
+    LoweringException,
+    jax.errors.JaxRuntimeError,
+)
+
 
 def _round_up(x: int, mult: int) -> int:
     return -(-x // mult) * mult
@@ -74,16 +84,21 @@ def _round_up(x: int, mult: int) -> int:
 def _pick_tiles_f32(m: int, k: int, n: int) -> tuple:
     """Default tiles for the f32-limb kernel.
 
-    Alignment floors come from the TPU layout: sublane (second-to-minor)
-    tiles are multiples of 8, lane (minor) tiles multiples of 128.
-    Small dims get a single right-sized tile instead of padding up to
-    the historical 128/128/256; ``bk <= LAZY_K`` (k <= 128) additionally
-    enables the kernel's lazy-reduction path.  Caps keep the worst-case
-    VMEM block footprint (a + b + out) around 1 MiB.
+    A dim that fits one tile is one block of its full size: a block
+    equal to the whole array dim needs no TPU (8, 128) alignment, so
+    nothing is padded.  Padding a short dim up to the alignment would
+    copy the other operand ~alignment/dim times, which at published
+    widths does not fit the chip.  Larger dims get aligned tiles
+    (sublane 8, lane 128), K preferring a depth that divides it;
+    ``bk <= LAZY_K`` enables the kernel's lazy-reduction path.  Caps
+    keep the VMEM block footprint (a + b + out) near 1 MiB.
     """
-    bm = _round_up(m, 8) if m <= 256 else 128
-    bn = _round_up(n, 128) if n <= 512 else 128
-    bk = 128 if k <= 128 else 256
+    bm = m if m <= 256 else 128
+    bn = n if n <= 512 else 128
+    if k <= 256:
+        bk = k
+    else:
+        bk = 128 if k % 256 and not k % 128 else 256
     return bm, bn, bk
 
 
@@ -92,9 +107,8 @@ def _pick_tiles_int32(m: int, k: int, n: int) -> tuple:
     the K tile is freed from the 2**24 f32 ceiling — deeper bk means
     fewer Barrett recombinations per output tile.  Capped at 2048 to
     keep the int32 operand blocks inside the ~1 MiB VMEM budget."""
-    bm = _round_up(m, 8) if m <= 256 else 128
-    bn = _round_up(n, 128) if n <= 512 else 128
-    bk = min(_round_up(k, 128), 2048)
+    bm, bn, _ = _pick_tiles_f32(m, k, n)
+    bk = k if k <= 2048 else 2048
     return bm, bn, bk
 
 
@@ -166,18 +180,21 @@ def autotune_tiles(
     a = jax.random.randint(rng_a, shape_a, 0, p, dtype=jnp.int32)
     b = jax.random.randint(jax.random.PRNGKey(1), shape_b, 0, p, dtype=jnp.int32)
     best, best_t = None, float("inf")
+    refused = REGISTRY.counter("kernels.autotune_refused")
     for bm, bn, bk in candidates:
         try:
-            run = functools.partial(
-                mod_matmul, a, b, p=p, backend=backend,
+            run = mod_matmul.lower(
+                a, b, p=p, backend=backend,
                 bm=bm, bn=bn, bk=bk, interpret=interpret,
-            )
-            run().block_until_ready()  # compile
-            t = min(
-                _timed(run) for _ in range(max(1, repeats))
-            )
-        except Exception:
-            continue  # candidate invalid for this backend/shape
+            ).compile()
+        except _COMPILE_REFUSALS:
+            # the tiling is invalid for this backend/shape, or the
+            # chip's compiler refused it; a failure at run time is not
+            # a refusal and propagates
+            refused.inc()
+            continue
+        run(a, b).block_until_ready()
+        t = min(_timed(run, a, b) for _ in range(max(1, repeats)))
         if t < best_t:
             best, best_t = (bm, bn, bk), t
     if best is None:
@@ -186,9 +203,9 @@ def autotune_tiles(
     return best
 
 
-def _timed(run) -> float:
+def _timed(run, *args) -> float:
     t0 = time.perf_counter()
-    run().block_until_ready()
+    run(*args).block_until_ready()
     return time.perf_counter() - t0
 
 

@@ -15,19 +15,10 @@ import math
 from typing import Optional
 
 import jax
-
-# jax.sharding.AxisType (and the axis_types= kwarg of jax.make_mesh)
-# only exist on newer JAX releases; on older installs every axis is
-# implicitly Auto, so the kwarg is simply dropped.
-try:
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed JAX
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _axis_kw(n):
-    if AxisType is None:
-        return {}
     return {"axis_types": (AxisType.Auto,) * n}
 
 

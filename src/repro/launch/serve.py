@@ -18,6 +18,7 @@ import time
 import jax
 import numpy as np
 
+from ..compile_cache import setup_compile_cache
 from ..configs import SHAPES, get_config, reduced as reduce_cfg
 from ..models import build_model
 from .mesh import describe, make_elastic_mesh, make_mesh
@@ -42,6 +43,7 @@ def main():
         help="simulated edge pool size for --private-head",
     )
     args = ap.parse_args()
+    setup_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

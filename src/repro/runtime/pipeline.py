@@ -64,6 +64,7 @@ import numpy as np
 from ..core import protocol as proto
 from ..core.planner import CMPCPlan
 from ..obs.metrics import REGISTRY
+from ..obs.tracer import TRACER
 from .metrics import PipelineMetrics, RunMetrics
 from .pool import WorkerTrace
 from .scheduler import (
@@ -285,7 +286,15 @@ class PipelineSession:
         :class:`~repro.runtime.scheduler.DecodeFailure` exactly like
         the standalone entry points; a failed append leaves the
         occupancy state untouched (the replay never ran).
+
+        With tracing on, a ``runtime.replay`` wall span covers the
+        whole append: shares, exchange, the device-to-host copy of the
+        I-evaluations and the decode.
         """
+        with TRACER.span("runtime.replay", replay=len(self._replays)):
+            return self._append(a, b, trace, not_before, obs_attrs)
+
+    def _append(self, a, b, trace, not_before, obs_attrs) -> PipelineReplay:
         k = len(self._replays)
         if self.planner is None:
             decision = None

@@ -190,9 +190,15 @@ def test_constant_lhs_not_broadcast_in_launch():
 
 
 def test_pick_tiles_alignment_and_adaptivity():
-    for m, k, n in [(1, 1, 1), (10, 6, 1024), (32, 32, 32), (300, 700, 513)]:
+    for m, k, n in [(1, 1, 1), (10, 6, 1024), (32, 32, 32), (300, 700, 513),
+                    (21, 6, 3317760), (8, 1152, 2880)]:
         bm, bn, bk = pick_tiles(m, k, n)
-        assert bm % 8 == 0 and bn % 128 == 0 and bk in (128, 256)
+        # each tile is the whole dim (no TPU alignment needed, nothing
+        # padded) or an aligned tile: sublane 8, lane 128, K 128/256
+        assert bm == m or bm % 8 == 0
+        assert bn == n or bn % 128 == 0
+        assert bk == k or bk in (128, 256)
+        assert bk <= 256  # exact f32 limb accumulation
         # adaptive tiles never waste more than the fixed 128/128/256 tiling
         assert padding_waste(m, k, n, (bm, bn, bk)) <= padding_waste(
             m, k, n, (128, 128, 256)
@@ -207,6 +213,12 @@ def test_pick_tiles_alignment_and_adaptivity():
     assert macs(17, 6, 1024, pick_tiles(17, 6, 1024)) * 4 < macs(
         17, 6, 1024, (128, 128, 256)
     )
+    # short dims are never padded: the share phase's K = 6 stays 6
+    assert padded_shape(21, 6, 3317760, pick_tiles(21, 6, 3317760)) == (
+        21, 6, 3317760
+    )
+    # a deep K takes a depth that divides it (1152 = 9 * 128)
+    assert pick_tiles(8, 1152, 2880)[2] == 128
 
 
 def test_explicit_tiles_still_win():

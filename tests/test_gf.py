@@ -83,3 +83,14 @@ def test_encode_decode_roundtrip(f):
 def test_encode_overflow_raises(f):
     with pytest.raises(OverflowError):
         f.encode(np.array([1e6]), 256)
+
+
+def test_matmul_rejects_non_2d_rhs(f):
+    """A batched (or 1D) right-hand side is refused loudly: the chunk
+    loop would slice its batch axis as the contraction."""
+    a = np.ones((2, 3), np.int64)
+    with pytest.raises(ValueError, match="2D right-hand side"):
+        f.matmul(a, np.ones((4, 3, 5), np.int64))
+    with pytest.raises(ValueError, match="2D right-hand side"):
+        f.matmul(a, np.ones(3, np.int64))
+    assert np.array_equal(f.matmul(a, np.ones((3, 5), np.int64)), np.full((2, 5), 3))

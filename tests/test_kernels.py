@@ -96,3 +96,21 @@ def test_pallas_property(m, k, n, seed):
     b = rng.integers(0, p, (k, n)).astype(np.int32)
     got = np.asarray(mod_matmul(a, b, p=p, backend="pallas", interpret=True))
     assert np.array_equal(modmatmul_ref(a, b, p), got)
+
+
+def test_autotune_skips_and_counts_refused_tilings():
+    """A tiling the kernel refuses is skipped and counted; the valid
+    candidate wins and is pinned."""
+    from repro.kernels.modmatmul import ops
+    from repro.obs.metrics import REGISTRY
+
+    refused = REGISTRY.counter("kernels.autotune_refused")
+    before = refused.value
+    best = ops.autotune_tiles(
+        16, 32, 128, backend="pallas", candidates=[(16, 128, 512), (16, 128, 32)],
+        repeats=1, interpret=True,
+    )
+    assert best == (16, 128, 32)
+    assert refused.value == before + 1  # bk = 512 breaks exact f32 sums
+    assert ops.pick_tiles(16, 32, 128) == best
+    ops._AUTOTUNE_CACHE.pop(("pallas", 16, 32, 128))

@@ -112,3 +112,28 @@ def test_large_pool_passes_int32_bound():
     mesh = Mesh(np.array(jax.devices()), ("workers",))
     i_evals = run_phase2_sharded(plan, fa, fb, noise, mesh)
     assert np.array_equal(proto.reconstruct(plan, i_evals), field.matmul(a.T, b))
+
+
+def test_phase2_program_compiled_once_per_shape(setup):
+    """Replays of one shape reuse the jitted exchange: the shard_map
+    program is cached per (mesh, mode, shape), not rebuilt per call."""
+    from repro.core import distributed
+
+    plan, a, b, want, mesh = setup
+    field = Field()
+    rng = np.random.default_rng(21)
+    fa = np.stack([np.asarray(proto.share_a(plan, a[i], rng)) for i in range(3)])
+    fb = np.stack([np.asarray(proto.share_b(plan, b[i], rng)) for i in range(3)])
+    noise = field.random(rng, (3, plan.n_workers, plan.scheme.z) + plan.shapes.blk_y)
+    run_phase2_sharded(plan, fa, fb, noise, mesh, mode="psum_scatter")
+    programs = distributed._phase2_program.cache_info().currsize
+    for _ in range(2):
+        i_evals = run_phase2_sharded(plan, fa, fb, noise, mesh, mode="psum_scatter")
+    assert distributed._phase2_program.cache_info().currsize == programs
+    first = distributed._phase2_program(
+        mesh, "workers", "psum_scatter", "auto", plan.field.p, plan.scheme.z,
+        3, *plan.shapes.blk_y,
+    )
+    assert first._cache_size() == 1  # traced and compiled once
+    for i in range(3):
+        assert np.array_equal(proto.reconstruct(plan, i_evals[i]), want[i])

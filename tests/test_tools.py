@@ -1,5 +1,4 @@
-"""Repo tooling: the JAX-shim lint (`tools/check_api_shims.py`) and the
-benchmark drift diff (`tools/bench_diff.py`)."""
+"""Repo tooling: the benchmark drift diff (`tools/bench_diff.py`)."""
 import json
 import os
 import subprocess
@@ -9,14 +8,6 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(ROOT, "tools"))
 
 import bench_diff  # noqa: E402
-import check_api_shims  # noqa: E402
-
-
-# ----------------------------------------------------------------------
-# shim lint
-# ----------------------------------------------------------------------
-def test_repo_is_shim_clean():
-    assert check_api_shims.violations(ROOT) == []
 
 
 def _write(root, rel, text):
@@ -24,47 +15,6 @@ def _write(root, rel, text):
     os.makedirs(os.path.dirname(path), exist_ok=True)
     with open(path, "w") as fh:
         fh.write(text)
-
-
-def test_lint_flags_attribute_import_and_getattr(tmp_path):
-    root = str(tmp_path)
-    _write(root, "src/bad_attr.py", "import jax\njax.shard_map(f)\n")
-    _write(root, "src/bad_from.py", "from jax import shard_map\n")
-    _write(root, "src/bad_getattr.py", 'x = getattr(pl, "CompilerParams")\n')
-    _write(root, "src/fine.py", "# shard_map only in this comment\nx = 1\n")
-    found = check_api_shims.violations(root)
-    flagged = {v[0] for v in found}
-    assert flagged == {
-        os.path.join("src", "bad_attr.py"),
-        os.path.join("src", "bad_from.py"),
-        os.path.join("src", "bad_getattr.py"),
-    }
-
-
-def test_lint_skips_the_sanctioned_shims(tmp_path):
-    root = str(tmp_path)
-    shim = os.path.join("src", "repro", "compat.py")
-    assert shim in check_api_shims.ALLOWED
-    _write(root, shim, "from jax import shard_map\n")
-    _write(root, "src/elsewhere.py", "from jax import shard_map\n")
-    flagged = {v[0] for v in check_api_shims.violations(root)}
-    assert flagged == {os.path.join("src", "elsewhere.py")}
-
-
-def test_lint_reports_unparsable_files(tmp_path):
-    root = str(tmp_path)
-    _write(root, "src/broken.py", "def broken(:\n")
-    found = check_api_shims.violations(root)
-    assert len(found) == 1 and "syntax" in found[0][2]
-
-
-def test_lint_cli_on_repo():
-    proc = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "tools", "check_api_shims.py"),
-         ROOT],
-        capture_output=True, text=True,
-    )
-    assert proc.returncode == 0, proc.stderr
 
 
 # ----------------------------------------------------------------------
