@@ -1,0 +1,7 @@
+"""Bytes handed to the device per replay: the ``bytes`` of ``runtime.upload``
+(the int32 operand arrays on the device)."""
+from bench.spans import per_replay
+
+
+def read(ctx):
+    return per_replay(ctx, "runtime.upload", "bytes")

@@ -18,11 +18,18 @@ Design constraints, in order:
    ``span()`` returns a module-level singleton no-op context manager
    when disabled, so the instrumented hot path allocates *nothing* —
    no span objects, no dicts, no ids (regression-tested).
-2. **Zero dependencies.**  ``threading`` + ``time`` + ``itertools``.
+2. **Zero dependencies.**  ``threading`` + ``time`` + ``itertools``;
+   JAX is looked up only when the tracer is enabled (point 4).
 3. **Deterministic simulated events.**  Sim-clock records carry only
    caller-provided timestamps and attributes, so two byte-identical
    replays produce byte-identical sim-track traces (the wall track is
    inherently machine-dependent and is kept separable).
+4. **Wall spans in the profiler's trace.**  While enabled, every wall
+   span also opens a ``jax.profiler.TraceAnnotation`` of its name, so
+   a ``jax.profiler`` trace shows the program's spans on the profiler's
+   own host clock, with each device program under the span that
+   launched it.  The annotator is resolved by ``enable()`` (none when
+   JAX is not installed), or passed in as ``Tracer(annotator=...)``.
 
 Record shape (a plain dict per event, see ``Tracer.events``):
 
@@ -44,13 +51,22 @@ from __future__ import annotations
 import itertools
 import threading
 import time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 # Hard cap on buffered events: a runaway loop with tracing enabled
 # degrades to dropped events (counted) instead of unbounded memory.
 MAX_EVENTS_DEFAULT = 1_000_000
 
 SimTrack = Tuple[str, int]
+
+
+def _profiler_annotator() -> Optional[Callable[[str], Any]]:
+    """``jax.profiler.TraceAnnotation``, or None without JAX."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
 
 
 class _DisabledSpan:
@@ -83,7 +99,7 @@ class Span:
     attributes mid-flight.
     """
 
-    __slots__ = ("_tracer", "name", "attrs", "id", "parent", "t0", "_track")
+    __slots__ = ("_tracer", "name", "attrs", "id", "parent", "t0", "_track", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any]):
         self._tracer = tracer
@@ -93,6 +109,7 @@ class Span:
         self.parent = 0
         self.t0 = 0.0
         self._track = 0
+        self._ann = None
 
     def set(self, **attrs) -> "Span":
         self.attrs.update(attrs)
@@ -104,12 +121,17 @@ class Span:
         self.parent = stack[-1] if stack else 0
         stack.append(self.id)
         self._track = threading.get_ident()
+        if tr._annotate is not None:
+            self._ann = tr._annotate(self.name)
+            self._ann.__enter__()
         self.t0 = tr._clock()
         return self
 
     def __exit__(self, *exc) -> bool:
         tr = self._tracer
         t1 = tr._clock()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         stack = tr._stack()
         if stack and stack[-1] == self.id:
             stack.pop()
@@ -132,8 +154,14 @@ class Span:
 class Tracer:
     """Thread-safe two-clock event recorder (module docstring)."""
 
-    def __init__(self, max_events: int = MAX_EVENTS_DEFAULT, clock=time.perf_counter):
+    def __init__(
+        self,
+        max_events: int = MAX_EVENTS_DEFAULT,
+        clock=time.perf_counter,
+        annotator: Optional[Callable[[str], Any]] = None,
+    ):
         self.enabled = False
+        self._annotate = annotator
         self.max_events = int(max_events)
         self._clock = clock
         self._events: List[dict] = []
@@ -144,6 +172,8 @@ class Tracer:
 
     # -- lifecycle -----------------------------------------------------
     def enable(self) -> "Tracer":
+        if self._annotate is None:
+            self._annotate = _profiler_annotator()
         self.enabled = True
         return self
 

@@ -289,7 +289,9 @@ class PipelineSession:
 
         With tracing on, a ``runtime.replay`` wall span covers the
         whole append: shares, exchange, the device-to-host copy of the
-        I-evaluations and the decode.
+        I-evaluations and the decode.  Inside it, ``runtime.upload``
+        covers the operands' reduction mod p, int32 cast and transfer
+        to the device (``bytes``: the device arrays' size).
         """
         with TRACER.span("runtime.replay", replay=len(self._replays)):
             return self._append(a, b, trace, not_before, obs_attrs)
@@ -359,7 +361,9 @@ class PipelineSession:
         # (a DecodeFailure must not half-advance the occupancy).
 
         # -- numeric path: same batched engine as run_batch_over_pool --
-        a_j, b_j = proto._prep_batched_operands(plan_k, a, b)
+        with TRACER.span("runtime.upload", replay=k) as sp:
+            a_j, b_j = proto._prep_batched_operands(plan_k, a, b)
+            sp.set(bytes=int(a_j.nbytes + b_j.nbytes))
         batch = int(a_j.shape[0])
         fa, fb = proto.share_batched(
             plan_k, a_j, b_j, jax.random.fold_in(self._key, k),
@@ -367,7 +371,7 @@ class PipelineSession:
         )
         compute_i_all = _batched_compute_closure(
             plan_k, fa, fb, rng, batch, self._mesh, self._axis, self._mode,
-            self._backend,
+            self._backend, replay=k,
         )
         # Trace annotations: lane index + absolute start, plus the
         # deciding PlanDecision when a planner drives the pipeline

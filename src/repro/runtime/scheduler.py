@@ -301,6 +301,7 @@ def _replay_events(
     decided.
     """
     tracing = TRACER.enabled
+    replay = (obs_attrs or {}).get("replay")  # session index on the wall spans
     p = plan.field.p
     share_at = trace.share_delay if share_arrival is None else share_arrival
     phase1_last = float(share_at[alive].max())
@@ -352,9 +353,15 @@ def _replay_events(
             # canonical subset-cache key).
             phase2_ids = np.sort(np.array(computed))
             phase2_set_time = t_now
-            # np.array (not asarray): device outputs are read-only views
-            # and corrupt rows are overwritten below.
-            i_all = np.array(compute_i_all(phase2_ids))
+            # Waiting for the device and copying its result to the host
+            # are timed apart.  np.array (not asarray): device outputs are
+            # read-only views and corrupt rows are overwritten below.
+            i_dev = compute_i_all(phase2_ids)
+            with TRACER.span("runtime.device_wait", replay=replay):
+                jax.block_until_ready(i_dev)
+            with TRACER.span("runtime.fetch", replay=replay) as sp:
+                i_all = np.array(i_dev)
+                sp.set(bytes=int(i_all.nbytes))
             # Corrupt workers respond with garbage of the right shape
             # (garbage spans their whole payload — every product of a
             # batched replay sees the same worker corrupt).
@@ -704,36 +711,40 @@ def _batched_compute_closure(
     axis: str,
     mode: str,
     backend: str,
+    replay: int = 0,
 ) -> Callable[[np.ndarray], np.ndarray]:
     """``compute_i_all`` for a batched replay (shared with the pipeline).
 
     Folds the whole batch into each worker's payload so one Phase-2
     pass serves every product; with ``mesh`` the exchange is the real
     ``shard_map`` collective driven by the scheduler's fastest subset.
+    A ``protocol.phase2`` wall span (``replay`` = session index) covers
+    each call, so every Phase-2 device program is launched under it.
     """
     bry, bcy = plan.shapes.blk_y
 
     def compute_i_all(phase2_ids: np.ndarray) -> np.ndarray:
-        if mesh is not None:
-            # Faithful distributed exchange: per-worker blinding draws,
-            # whole batch on one collective, sender subset = the
-            # scheduler's fastest n_workers.
-            noise = plan.field.random(
-                rng, (batch, plan.n_workers, plan.scheme.z, bry, bcy)
-            )
-            i_b = run_phase2_sharded(
-                plan, fa, fb, noise, mesh,
-                axis=axis, mode=mode, matmul_backend=backend,
-                worker_ids=phase2_ids,
-            )  # [batch, n_total, bry, bcy]
-            return np.moveaxis(np.asarray(i_b), 1, 0).reshape(
-                plan.n_total, batch * bry, bcy
-            )
-        # Dense simulation: fold the batch into the block rows so the
-        # existing degree-reduction matmul serves every product at once.
-        h = proto.worker_multiply(plan, fa, fb)  # [batch, n_total, bry, bcy]
-        h_w = jnp.moveaxis(h, 0, 1).reshape(plan.n_total, batch * bry, bcy)
-        return proto.degree_reduce(plan, h_w, rng, worker_ids=phase2_ids)
+        with TRACER.span("protocol.phase2", replay=replay):
+            if mesh is not None:
+                # Faithful distributed exchange: per-worker blinding draws,
+                # whole batch on one collective, sender subset = the
+                # scheduler's fastest n_workers.
+                noise = plan.field.random(
+                    rng, (batch, plan.n_workers, plan.scheme.z, bry, bcy)
+                )
+                i_b = run_phase2_sharded(
+                    plan, fa, fb, noise, mesh,
+                    axis=axis, mode=mode, matmul_backend=backend,
+                    worker_ids=phase2_ids,
+                )  # [batch, n_total, bry, bcy]
+                return np.moveaxis(np.asarray(i_b), 1, 0).reshape(
+                    plan.n_total, batch * bry, bcy
+                )
+            # Dense simulation: fold the batch into the block rows so the
+            # existing degree-reduction matmul serves every product at once.
+            h = proto.worker_multiply(plan, fa, fb)  # [batch, n_total, bry, bcy]
+            h_w = jnp.moveaxis(h, 0, 1).reshape(plan.n_total, batch * bry, bcy)
+            return proto.degree_reduce(plan, h_w, rng, worker_ids=phase2_ids)
 
     return compute_i_all
 

@@ -64,7 +64,6 @@ from ..core.constructions import PlanConfig
 from ..core.gf import Field
 from ..core.layers import choose_scales
 from ..core.planner import BlockShapes, CMPCPlan, get_plan_for
-from ..obs.metrics import REGISTRY
 from ..obs.tracer import TRACER
 from ..runtime.metrics import estimate_pool, observed_run
 from ..runtime.pipeline import PipelineRun, PipelineSession
@@ -235,7 +234,6 @@ class ServingEngine:
         self._next_rid += 1
         self._queue.append(req)
         self._all.append(req)
-        REGISTRY.counter("serve.requests").inc()
         return req
 
     # -- pool health / admission ----------------------------------------
@@ -285,7 +283,6 @@ class ServingEngine:
     def _shed(self, req: Request, t: float, reason: str) -> None:
         req.state = SHED
         req.shed_reason = reason
-        REGISTRY.counter("serve.shed").inc()
         if TRACER.enabled:
             TRACER.sim_event(
                 "serve.shed", float(t), track=("request", req.rid),
@@ -378,9 +375,9 @@ class ServingEngine:
         k_dim, out = self.w.shape
         with TRACER.span("serve.run", requests=len(self._queue)):
             while self._queue:
-                trace = self._peek_trace()
-                if self._pool_n != trace.n:
-                    if not self._reconfigure(trace.n):
+                with TRACER.span("serve.admit") as sp:
+                    trace = self._peek_trace()
+                    if self._pool_n != trace.n and not self._reconfigure(trace.n):
                         # Pool cannot seat the construction: nothing this
                         # engine launches can complete — shed the queue.
                         t = self._clock
@@ -388,61 +385,62 @@ class ServingEngine:
                             self._shed(r, t, "pool")
                         self._queue.clear()
                         break
-                t_ready = self._session.ready_at(
-                    self.pipe_depth if self.mode == "continuous" else 1
-                )
-                t_launch = max(t_ready, min(r.arrival for r in self._queue))
-                batch = self._admit(t_launch)
+                    sp.set(replay=self._session.depth)
+                    t_ready = self._session.ready_at(
+                        self.pipe_depth if self.mode == "continuous" else 1
+                    )
+                    t_launch = max(t_ready, min(r.arrival for r in self._queue))
+                    batch = self._admit(t_launch)
                 if not batch:
                     continue  # everything eligible was shed; queue shrank
                 self._t_idx += 1
-                scales = [
-                    choose_scales(
-                        k_dim,
-                        float(np.abs(r.x).max() + 1e-9),
-                        float(np.abs(self.w).max() + 1e-9),
-                        self.field.p,
-                    )
-                    for r in batch
-                ]
-                aq = np.stack([
-                    self.field.encode(r.x.T, s) for r, s in zip(batch, scales)
-                ])  # [batch, k, rows]
-                bq = np.stack([self._wq(s) for s in scales])  # [batch, k, out]
+                with TRACER.span("serve.encode", replay=self._session.depth) as sp:
+                    scales = [
+                        choose_scales(
+                            k_dim,
+                            float(np.abs(r.x).max() + 1e-9),
+                            float(np.abs(self.w).max() + 1e-9),
+                            self.field.p,
+                        )
+                        for r in batch
+                    ]
+                    aq = np.stack([
+                        self.field.encode(r.x.T, s) for r, s in zip(batch, scales)
+                    ])  # [batch, k, rows]
+                    bq = np.stack([self._wq(s) for s in scales])  # [batch, k, out]
+                    sp.set(bytes=int(aq.nbytes + bq.nbytes))
                 replay = self._session.append(
                     aq, bq, trace, not_before=t_launch,
                     obs_attrs={"n_requests": len(batch)},
                 )
                 self._obs.append(observed_run(replay.metrics, start=replay.start))
                 self._replays_total += 1
-                REGISTRY.counter("serve.replays").inc()
-                yq = np.asarray(replay.y)  # [batch, rows, out] field values
-                for i, (r, s) in enumerate(zip(batch, scales)):
-                    if self.validate:
-                        want = self.field.matmul(aq[i].T, bq[i])
-                        if not np.array_equal(yq[i], want):
-                            raise AssertionError(
-                                f"request {r.rid}: decode disagrees with the "
-                                f"field oracle on replay {replay.index}"
+                with TRACER.span("serve.decode", replay=replay.index):
+                    yq = np.asarray(replay.y)  # [batch, rows, out] field values
+                    for i, (r, s) in enumerate(zip(batch, scales)):
+                        if self.validate:
+                            want = self.field.matmul(aq[i].T, bq[i])
+                            if not np.array_equal(yq[i], want):
+                                raise AssertionError(
+                                    f"request {r.rid}: decode disagrees with the "
+                                    f"field oracle on replay {replay.index}"
+                                )
+                        r.y = self.field.decode(yq[i], s * s)
+                        r.state = DONE
+                        r.launch = replay.start
+                        r.completion = replay.completion
+                        r.replay = replay.index
+                        if TRACER.enabled:
+                            rtrack = ("request", r.rid)
+                            TRACER.sim_span(
+                                "serve.queue", r.arrival, replay.start,
+                                track=rtrack, request=r.rid, replay=replay.index,
                             )
-                    r.y = self.field.decode(yq[i], s * s)
-                    r.state = DONE
-                    r.launch = replay.start
-                    r.completion = replay.completion
-                    r.replay = replay.index
-                    if not r.met_deadline:
-                        REGISTRY.counter("serve.deadline_miss").inc()
-                    if TRACER.enabled:
-                        rtrack = ("request", r.rid)
-                        TRACER.sim_span(
-                            "serve.queue", r.arrival, replay.start,
-                            track=rtrack, request=r.rid, replay=replay.index,
-                        )
-                        TRACER.sim_span(
-                            "serve.service", replay.start, replay.completion,
-                            track=rtrack, request=r.rid, replay=replay.index,
-                            deadline_met=r.met_deadline,
-                        )
+                            TRACER.sim_span(
+                                "serve.service", replay.start, replay.completion,
+                                track=rtrack, request=r.rid, replay=replay.index,
+                                deadline_met=r.met_deadline,
+                            )
         return self.report()
 
     def report(self) -> EngineReport:

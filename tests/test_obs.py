@@ -8,6 +8,8 @@ live on separable tracks, and the exported JSON is schema-valid.
 """
 import dataclasses
 import json
+import os
+import pathlib
 
 import numpy as np
 import pytest
@@ -72,6 +74,73 @@ def test_disabled_tracer_allocates_nothing():
     with t.span("a") as sp:
         assert sp.set(extra=1) is sp
         assert sp.id == 0
+
+
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs opens/closes."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name):
+        log = self.log
+
+        class _Ann:
+            def __enter__(self):
+                log.append(("open", name))
+                return self
+
+            def __exit__(self, *exc):
+                log.append(("close", name))
+                return False
+
+        return _Ann()
+
+
+def test_enabled_span_opens_and_closes_one_annotation_of_its_name():
+    ann = _Annotations()
+    t = Tracer(annotator=ann).enable()
+    with t.span("outer"):
+        with t.span("inner"):
+            t.event("tick")
+    t.sim_span("sim", 0.0, 1.0)
+    assert ann.log == [
+        ("open", "outer"), ("open", "inner"), ("close", "inner"), ("close", "outer"),
+    ]
+    with pytest.raises(ValueError):
+        with t.span("failing"):
+            raise ValueError("boom")
+    assert ann.log[-2:] == [("open", "failing"), ("close", "failing")]
+
+
+def test_disabled_tracer_opens_no_annotation():
+    ann = _Annotations()
+    t = Tracer(annotator=ann)
+    assert t.span("a") is t.span("b") is _DISABLED_SPAN
+    with t.span("a"):
+        pass
+    t.enable().disable()
+    assert t.span("c") is _DISABLED_SPAN
+    assert ann.log == [] and t.events == []
+
+
+def test_obs_imports_and_traces_without_jax():
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "sys.modules['jax'] = None  # any import of jax now fails\n"
+        "import repro.obs as obs\n"
+        "t = obs.Tracer().enable()\n"
+        "with t.span('s'):\n"
+        "    pass\n"
+        "assert [e['name'] for e in t.events] == ['s']\n"
+    )
+    src = str(pathlib.Path(__file__).resolve().parents[1] / "src")
+    env = dict(os.environ, PYTHONPATH=src)
+    proc = subprocess.run([sys.executable, "-c", code], env=env, capture_output=True, text=True)
+    assert proc.returncode == 0, proc.stderr
 
 
 def test_nested_spans_record_parent_ids(tracer):

@@ -352,6 +352,73 @@ def test_serve_spans_link_queue_to_replay():
         assert svc["attrs"]["replay"] == r.replay
 
 
+def test_traced_run_splits_each_replay_into_named_host_spans(monkeypatch):
+    """One traced run() of two replays: per replay one each of the
+    engine's admit/encode/decode spans under serve.run, and of the
+    runtime's upload/phase-2/device-wait/fetch spans under that replay's
+    runtime.replay; the byte counts are the arrays' own sizes."""
+    import repro.runtime.pipeline as pipeline
+    from repro.core import protocol as proto
+
+    seen = {"encode": [], "upload": [], "fetch": []}
+    real_append = pipeline.PipelineSession.append
+    real_prep = proto._prep_batched_operands
+    real_closure = pipeline._batched_compute_closure
+
+    def append(self, a, b, *args, **kw):
+        seen["encode"].append(a.nbytes + b.nbytes)
+        return real_append(self, a, b, *args, **kw)
+
+    def prep(*args):
+        a, b = real_prep(*args)
+        seen["upload"].append(a.nbytes + b.nbytes)
+        return a, b
+
+    def closure(*args, **kw):
+        compute = real_closure(*args, **kw)
+
+        def recorded(ids):
+            out = compute(ids)
+            seen["fetch"].append(out.nbytes)
+            return out
+
+        return recorded
+
+    monkeypatch.setattr(pipeline.PipelineSession, "append", append)
+    monkeypatch.setattr(proto, "_prep_batched_operands", prep)
+    monkeypatch.setattr(pipeline, "_batched_compute_closure", closure)
+    TRACER.clear()
+    TRACER.enable()
+    try:
+        eng, _, rng = _engine(max_batch=2)
+        for i in range(4):
+            eng.submit(rng.normal(size=(ROWS, K_DIM)), 0.0)
+        rep = eng.run()
+    finally:
+        TRACER.disable()
+    walls = [e for e in TRACER.events if e["clock"] == "wall" and e["kind"] == "span"]
+    TRACER.clear()
+    assert rep.replays == 2
+    by_name = {}
+    for e in walls:
+        by_name.setdefault(e["name"], []).append(e)
+    (run,) = by_name["serve.run"]
+    replays = by_name["runtime.replay"]
+    assert [e["attrs"]["replay"] for e in replays] == [0, 1]
+    assert all(e["parent"] == run["id"] for e in replays)
+    for name in ("serve.admit", "serve.encode", "serve.decode"):
+        assert [e["attrs"]["replay"] for e in by_name[name]] == [0, 1], name
+        assert all(e["parent"] == run["id"] for e in by_name[name]), name
+    for name in ("runtime.upload", "protocol.phase2", "runtime.device_wait", "runtime.fetch"):
+        spans = by_name[name]
+        assert [e["attrs"]["replay"] for e in spans] == [0, 1], name
+        assert [e["parent"] for e in spans] == [e["id"] for e in replays], name
+    assert [e["attrs"]["bytes"] for e in by_name["serve.encode"]] == seen["encode"]
+    assert [e["attrs"]["bytes"] for e in by_name["runtime.upload"]] == seen["upload"]
+    assert [e["attrs"]["bytes"] for e in by_name["runtime.fetch"]] == seen["fetch"]
+    assert all(b > 0 for b in seen["encode"] + seen["upload"] + seen["fetch"])
+
+
 # ----------------------------------------------------------------------
 # the async submission API under the engine
 # ----------------------------------------------------------------------
