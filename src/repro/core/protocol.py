@@ -658,12 +658,30 @@ def _run_batched_jit(
     )
 
 
+@functools.partial(jax.jit, static_argnums=1)
+def _mod_i32(x: jnp.ndarray, p: int) -> jnp.ndarray:
+    # p is static: on a TPU v5e, at the served B operand's size
+    # ([8, 2304, 5760] int32), a remainder by a constant compiles in
+    # about 1 s and runs in 2.1 ms; by a traced divisor, 35 s and 7.9 ms
+    return jnp.mod(x, p).astype(jnp.int32)
+
+
+def _field_i32(x, p: int) -> jnp.ndarray:
+    """``x mod p`` as an int32 device array.  A ``jax.Array`` is reduced
+    where it lives, on the device; anything else is reduced on the host
+    and copied to the device."""
+    if isinstance(x, jax.Array):
+        return _mod_i32(x, p)
+    return jnp.asarray(np.asarray(x) % p, jnp.int32)
+
+
 def _prep_batched_operands(
-    plan: CMPCPlan, a: np.ndarray, b: np.ndarray
+    plan: CMPCPlan, a, b
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Validate and promote operands to int32 [batch, k, m] device arrays."""
-    a = jnp.asarray(np.asarray(a) % plan.field.p, jnp.int32)
-    b = jnp.asarray(np.asarray(b) % plan.field.p, jnp.int32)
+    """Validate and promote operands (host or device arrays) to int32
+    [batch, k, m] device arrays."""
+    a = _field_i32(a, plan.field.p)
+    b = _field_i32(b, plan.field.p)
     if a.ndim == 2:
         a = a[None]
     if b.ndim == 2:

@@ -279,6 +279,8 @@ class PipelineSession:
     ) -> PipelineReplay:
         """Replay one batch (a: [batch, k, ma], b: [batch, k, mb]; 2D
         promotes to batch 1) against ``trace`` at the live occupancy.
+        Either operand may be a host array or a ``jax.Array`` already on
+        the device; a device operand is reduced mod p where it lives.
 
         ``not_before`` floors the upload start on every master link —
         the serving engine passes the launch time its admission loop
@@ -291,7 +293,8 @@ class PipelineSession:
         whole append: shares, exchange, the device-to-host copy of the
         I-evaluations and the decode.  Inside it, ``runtime.upload``
         covers the operands' reduction mod p, int32 cast and transfer
-        to the device (``bytes``: the device arrays' size).
+        to the device (``bytes``: what crossed from host to device, the
+        int32 size of the host operands alone).
         """
         with TRACER.span("runtime.replay", replay=len(self._replays)):
             return self._append(a, b, trace, not_before, obs_attrs)
@@ -313,14 +316,13 @@ class PipelineSession:
             # replan fast path).
             from .autoplan import plan_for_decision
 
-            a_dims = np.asarray(a)
-            b_dims = np.asarray(b)
+            a_shape, b_shape = np.shape(a), np.shape(b)
             decision = self.planner.decide(trace.n)
             plan_k = plan_for_decision(
                 decision,
-                int(a_dims.shape[-2]),
-                int(a_dims.shape[-1]),
-                int(b_dims.shape[-1]),
+                int(a_shape[-2]),
+                int(a_shape[-1]),
+                int(b_shape[-1]),
                 seed=self._plan_seed,
             )
         if self._n is None:
@@ -363,7 +365,11 @@ class PipelineSession:
         # -- numeric path: same batched engine as run_batch_over_pool --
         with TRACER.span("runtime.upload", replay=k) as sp:
             a_j, b_j = proto._prep_batched_operands(plan_k, a, b)
-            sp.set(bytes=int(a_j.nbytes + b_j.nbytes))
+            sp.set(bytes=sum(
+                int(x_j.nbytes)
+                for x, x_j in ((a, a_j), (b, b_j))
+                if not isinstance(x, jax.Array)
+            ))
         batch = int(a_j.shape[0])
         fa, fb = proto.share_batched(
             plan_k, a_j, b_j, jax.random.fold_in(self._key, k),

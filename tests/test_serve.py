@@ -355,12 +355,15 @@ def test_serve_spans_link_queue_to_replay():
 def test_traced_run_splits_each_replay_into_named_host_spans(monkeypatch):
     """One traced run() of two replays: per replay one each of the
     engine's admit/encode/decode spans under serve.run, and of the
-    runtime's upload/phase-2/device-wait/fetch spans under that replay's
-    runtime.replay; the byte counts are the arrays' own sizes."""
+    runtime.replay; the byte counts are the arrays' own sizes.  W's
+    residues already live on the device, so runtime.upload's bytes (what
+    crossed from host to device) are A's alone."""
+    import jax
+
     import repro.runtime.pipeline as pipeline
     from repro.core import protocol as proto
 
-    seen = {"encode": [], "upload": [], "fetch": []}
+    seen = {"encode": [], "upload": [], "fetch": [], "b_on_device": []}
     real_append = pipeline.PipelineSession.append
     real_prep = proto._prep_batched_operands
     real_closure = pipeline._batched_compute_closure
@@ -369,10 +372,11 @@ def test_traced_run_splits_each_replay_into_named_host_spans(monkeypatch):
         seen["encode"].append(a.nbytes + b.nbytes)
         return real_append(self, a, b, *args, **kw)
 
-    def prep(*args):
-        a, b = real_prep(*args)
-        seen["upload"].append(a.nbytes + b.nbytes)
-        return a, b
+    def prep(plan, a, b):
+        seen["b_on_device"].append(isinstance(b, jax.Array))
+        a_j, b_j = real_prep(plan, a, b)
+        seen["upload"].append(a_j.nbytes)
+        return a_j, b_j
 
     def closure(*args, **kw):
         compute = real_closure(*args, **kw)
@@ -417,6 +421,146 @@ def test_traced_run_splits_each_replay_into_named_host_spans(monkeypatch):
     assert [e["attrs"]["bytes"] for e in by_name["runtime.upload"]] == seen["upload"]
     assert [e["attrs"]["bytes"] for e in by_name["runtime.fetch"]] == seen["fetch"]
     assert all(b > 0 for b in seen["encode"] + seen["upload"] + seen["fetch"])
+    assert seen["b_on_device"] == [True, True]
+    assert all(u < e for u, e in zip(seen["upload"], seen["encode"]))
+    assert [e["attrs"]["requests"] for e in by_name["serve.encode"]] == [2, 2]
+
+
+# ----------------------------------------------------------------------
+# W's residues resident on the device
+# ----------------------------------------------------------------------
+def _host_stack(monkeypatch):
+    """Build every replay's B operand on the host instead, as int64 field
+    values: the runtime then reduces and uploads all of it."""
+    import repro.serve.engine as engine_mod
+
+    monkeypatch.setattr(
+        engine_mod, "_stack",
+        lambda ws: np.stack([np.asarray(w, np.int64) for w in ws]),
+    )
+
+
+def _x(rng, mag):
+    """Request rows whose largest magnitude is exactly ``mag``, so the
+    request's fixed-point scale depends on ``mag`` alone."""
+    x = rng.uniform(-mag, mag, size=(ROWS, K_DIM))
+    x[0, 0] = mag
+    return x
+
+
+def _encode_spans(submit, **kw):
+    """Run one engine with tracing on; (requests, serve.encode attrs)."""
+    TRACER.clear()
+    TRACER.enable()
+    try:
+        eng, w, rng = _engine(**kw)
+        reqs = submit(eng, rng)
+        eng.run()
+    finally:
+        TRACER.disable()
+    attrs = [
+        e["attrs"] for e in TRACER.events
+        if e["clock"] == "wall" and e["kind"] == "span" and e["name"] == "serve.encode"
+    ]
+    TRACER.clear()
+    return eng, w, reqs, attrs
+
+
+@pytest.mark.parametrize(
+    "mags, max_batch",
+    [
+        ((1.0,) * 8, 4),  # two full batches, one scale
+        ((1.0,) * 6, 4),  # a full batch, then a partial one
+        ((0.01, 1.0, 30.0, 1.0, 0.01, 300.0), 8),  # one batch, four scales
+    ],
+    ids=["full", "partial", "mixed-scales"],
+)
+def test_resident_w_decodes_bit_identically_to_host_stack(monkeypatch, mags, max_batch):
+    """At one seed, the engine's device-resident W gives the same decoded
+    Y, bit for bit, as B stacked on the host and uploaded every replay;
+    each distinct scale is uploaded once, and the counters say so."""
+
+    def submit(eng, rng):
+        return [eng.submit(_x(rng, m), 0.0) for m in mags]
+
+    eng, w, reqs, attrs = _encode_spans(submit, max_batch=max_batch)
+    with monkeypatch.context() as m:
+        _host_stack(m)
+        _, _, host_reqs, _ = _encode_spans(submit, max_batch=max_batch)
+    assert all(r.state == DONE for r in reqs + host_reqs)
+    for r, h in zip(reqs, host_reqs):
+        assert r.replay == h.replay
+        assert np.array_equal(r.y, h.y)
+        assert np.array_equal(r.y, _exact_y(r.x, w))
+    scales = {
+        choose_scales(K_DIM, float(np.abs(r.x).max() + 1e-9),
+                      float(np.abs(w).max() + 1e-9), FIELD.p)
+        for r in reqs
+    }
+    assert sorted(eng._w_dev) == sorted(scales)
+    assert len(scales) == (4 if len(set(mags)) > 1 else 1)
+    w_bytes = K_DIM * OUT * 4  # W's int32 residues at one scale
+    assert sum(a["requests"] for a in attrs) == len(mags)
+    assert sum(a["requests"] - a["w_hits"] for a in attrs) == len(scales)
+    assert sum(a["w_bytes"] for a in attrs) == len(scales) * w_bytes
+
+
+def test_w_residues_cross_to_the_device_once_per_scale():
+    """w_bytes > 0 only on the replay that first meets a scale: later
+    replays, and later run() waves, at that scale are all hits."""
+    TRACER.clear()
+    TRACER.enable()
+    try:
+        eng, w, rng = _engine(max_batch=2)
+        for mag in (1.0, 1.0, 100.0):  # a new scale on the third wave
+            for _ in range(4):
+                eng.submit(_x(rng, mag), 0.0)
+            eng.run()
+    finally:
+        TRACER.disable()
+    attrs = [
+        e["attrs"] for e in TRACER.events
+        if e["clock"] == "wall" and e["kind"] == "span" and e["name"] == "serve.encode"
+    ]
+    TRACER.clear()
+    w_bytes = K_DIM * OUT * 4
+    assert [a["requests"] for a in attrs] == [2] * 6
+    assert [a["w_bytes"] for a in attrs] == [w_bytes, 0, 0, 0, w_bytes, 0]
+    assert [a["w_hits"] for a in attrs] == [1, 2, 2, 2, 1, 2]
+    assert len(eng._w_dev) == 2
+
+
+def test_prep_operands_same_for_host_and_device_input():
+    """_prep_batched_operands gives the same int32 operands for a numpy
+    array and a jax.Array holding the same integers, negatives and
+    values >= p included; a device input is reduced on the device."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import protocol as proto
+
+    plan = get_plan_for(
+        PlanConfig("age", 2, 2, 1), BlockShapes(k=8, ma=4, mb=4, s=2, t=2),
+        field=FIELD,
+    )
+    rng = np.random.default_rng(3)
+    p = FIELD.p
+    a = rng.integers(-3 * p, 3 * p, size=(2, 8, 4))
+    b = rng.integers(-3 * p, 3 * p, size=(2, 8, 4))
+    b[0, 0, :4] = [-1, p, -p, 2 * p + 5]
+    a_h, b_h = proto._prep_batched_operands(plan, a, b)
+    a_d, b_d = proto._prep_batched_operands(
+        plan, jnp.asarray(a, jnp.int32), jnp.asarray(b, jnp.int32)
+    )
+    for host, dev, want in ((a_h, a_d, a % p), (b_h, b_d, b % p)):
+        assert isinstance(dev, jax.Array)
+        assert host.dtype == dev.dtype == jnp.int32
+        assert np.array_equal(np.asarray(host), want)
+        assert np.array_equal(np.asarray(dev), want)
+    # 2D inputs promote to batch 1 on either path
+    a2, b2 = proto._prep_batched_operands(plan, jnp.asarray(a[0], jnp.int32), b[0])
+    assert a2.shape == (1, 8, 4) and b2.shape == (1, 8, 4)
+    assert np.array_equal(np.asarray(a2[0]), a[0] % p)
 
 
 # ----------------------------------------------------------------------
