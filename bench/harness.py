@@ -13,12 +13,13 @@ import shutil
 import sys
 import time
 import traceback
-from dataclasses import dataclass
+from dataclasses import dataclass, field
+from types import ModuleType
 from typing import Any, Callable, Dict, List, Optional, TextIO
 
-from . import counts, reference
+from . import counts, layers, reference
 from .traffic import Sent, Traffic, Window, drive_closed, drive_open
-from .workload import Activations, Deployment, make_engine, make_weights
+from .workload import Activations
 
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
 METRICS_DIR = os.path.join(BENCH_DIR, "metrics")
@@ -27,7 +28,7 @@ BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _WARM_STREAM = 7  # activations for warm-up, apart from the timed stream
 
-# An exact comparison: both limits are 0 (see reference.py).
+# An exact comparison with the layer's reference: both limits are 0.
 LIMITS = {"mismatched_elements": 0, "missing_requests": 0}
 
 
@@ -78,10 +79,11 @@ def load_metric(name: str, unit: str, metrics_dir: str = METRICS_DIR) -> Metric:
 class Cell:
     name: str
     chips: int
-    deployment: Deployment
+    deployment: Any  # what ``layer.from_dict`` made of the configuration
     traffic: Traffic
     end_to_end: List[Metric]
     per_layer: List[Metric]
+    layer: ModuleType = field(default_factory=lambda: layers.load(layers.DEFAULT))
 
 
 @dataclass
@@ -140,15 +142,15 @@ def _served(window: Window) -> List[Served]:
     return out
 
 
-def _check(window: Window, w, p: int) -> tuple:
+def _check(window: Window, cell: Cell, weights) -> tuple:
     """(mismatched elements, missing requests, failed requests) against
-    the reference, over every request sent in the window."""
+    the layer's reference, over every request sent in the window."""
     from repro.serve import DONE
 
     done = [s.request for s in window.sent
             if s.request is not None and s.request.state == DONE]
     missing = len(window.sent) - len(done)
-    refs = reference.reference([r.x for r in done], w, p)
+    refs = cell.layer.reference(cell.deployment, weights, [r.x for r in done])
     bad = reference.mismatches([r.y for r in done], refs)
     return sum(bad), missing, missing + sum(1 for b in bad if b)
 
@@ -179,16 +181,16 @@ def run(
     TRACER.enable()
     dep, traffic = cell.deployment, cell.traffic
 
-    w = make_weights(dep, seed)
-    engine = make_engine(dep, w, seed, devices[: cell.chips])
-    warm = Activations(seed, traffic.rows, dep.k, stream=_WARM_STREAM)
+    weights = cell.layer.make_weights(dep, seed)
+    engine = cell.layer.make_engine(dep, weights, seed, devices[: cell.chips])
+    warm = Activations(seed, traffic.rows, dep.in_width, stream=_WARM_STREAM)
     for b in traffic.batch_sizes(dep.max_batch):
         for _ in range(b):
             engine.submit(warm.next(), 0.0)
         engine.run()
     setup_s = time.perf_counter() - t_start
 
-    acts = Activations(seed, traffic.rows, dep.k)
+    acts = Activations(seed, traffic.rows, dep.in_width)
     window = Window()
 
     def submit(due: float):
@@ -253,7 +255,7 @@ def run(
         peaks=peaks, device=device_trace,
     )
     del engine  # the program's state goes before the reference runs
-    mismatched, missing, failed = _check(window, w, dep.p)
+    mismatched, missing, failed = _check(window, cell, weights)
     compared = {"mismatched_elements": mismatched, "missing_requests": missing}
     correct = not crashed and all(compared[k] <= LIMITS[k] for k in LIMITS)
 

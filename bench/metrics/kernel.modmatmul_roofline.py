@@ -5,9 +5,9 @@ instruction name ``modmatmul_pallas``), the least time the chip needs is
 the larger of the compute bound (field multiply-adds x 2 over the bf16
 peak) and the memory bound (operand and result elements at 2 bytes over
 HBM bandwidth), counted by ``bench.counts`` from the call's shapes, with
-tile padding taken back off by the worker product the deployment
-defines.  The share is the summed least time over the summed device time
-of those calls.
+tile padding taken back off by the worker products the cell's layer
+defines (``worker_products``).  The share is the summed least time over
+the summed device time of those calls.
 """
 from bench import counts, trace_reduce
 from bench.harness import KERNEL
@@ -16,8 +16,8 @@ from bench.harness import KERNEL
 def read(ctx):
     if ctx.device is None:
         return None
-    dep = ctx.cell.deployment
-    logical = [counts.worker_product(dep.k, dep.out, ctx.cell.traffic.rows, dep.s, dep.t)]
+    cell = ctx.cell
+    logical = cell.layer.worker_products(cell.deployment, cell.traffic.rows)
     least = spent = 0.0
     for _chip, hlo, dur_ns in ctx.device.events_named(KERNEL):
         ops = trace_reduce.custom_call_operands(hlo)

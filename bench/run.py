@@ -7,13 +7,14 @@ Runs one cell of ``BENCHMARK.json`` on the chips of this machine:
 ``ServingEngine`` -> ``PipelineSession`` -> the jitted protocol phases ->
 the Pallas ``modmatmul`` kernel.  The cell names a configuration
 (``bench/configs/``), a traffic mix (``bench/traffic/``) and, through the
-metric lists, the readers in ``bench/metrics/``; each is found by name.
+metric lists, the readers in ``bench/metrics/``; the configuration names
+its layer (``bench/layers/``).  Each is found by name.
 
 With ``--trace 0`` the last stdout line holds the cell's end-to-end
 metrics, with ``--trace 1`` its per-layer metrics from a profiler trace
 of the window.  Every run compares each decoded ``Y`` with the plain
-reference (``bench/reference.py``) and prints the numbers compared, with
-their limits, as the last lines of stderr and under ``compared``.
+reference of its layer and prints the numbers compared, with their
+limits, as the last lines of stderr and under ``compared``.
 
 Exits 3 with no result line when JAX finds no TPU or too few chips.
 """
@@ -25,6 +26,7 @@ import argparse  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
+from typing import Optional  # noqa: E402
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCHMARK_JSON = os.path.join(ROOT, "BENCHMARK.json")
@@ -34,11 +36,16 @@ CACHE_DIR = os.path.join(ROOT, ".jax_cache")
 TRACE_DIR = os.path.join(ROOT, ".bench_trace")
 
 
-def load_cell(spec: dict, name: str, bench_dir: str):
-    """Resolve cell ``name`` of a ``BENCHMARK.json`` dict into a ``Cell``."""
+def load_cell(spec: dict, name: str, bench_dir: str, layers_dir: Optional[str] = None):
+    """Resolve cell ``name`` of a ``BENCHMARK.json`` dict into a ``Cell``.
+
+    The configuration's ``layer`` (``projection`` where it names none) is
+    the module of that name in ``layers_dir``, ``<bench_dir>/layers`` by
+    default.
+    """
+    from bench import layers
     from bench.harness import Cell, load_metric
     from bench.traffic import Traffic
-    from bench.workload import Deployment
 
     cells = {w["name"]: w for w in spec["workloads"]}
     if name not in cells:
@@ -46,7 +53,10 @@ def load_cell(spec: dict, name: str, bench_dir: str):
     wl = cells[name]
     configs = {c["name"]: c for c in spec["configs"]}
     with open(os.path.join(ROOT, configs[wl["config"]]["file"])) as f:
-        dep = Deployment.from_dict(json.load(f))
+        config = json.load(f)
+    layer = layers.load(config.get("layer", layers.DEFAULT),
+                        layers_dir or os.path.join(bench_dir, "layers"))
+    dep = layer.from_dict(config)
     with open(os.path.join(bench_dir, "traffic", f"{wl['traffic']}.json")) as f:
         traffic = Traffic.from_dict(json.load(f))
 
@@ -63,9 +73,9 @@ def load_cell(spec: dict, name: str, bench_dir: str):
     metrics_dir = os.path.join(bench_dir, "metrics")
     e2e = [load_metric(m["name"], m["unit"], metrics_dir)
            for m in spec["end_to_end"] if m["name"] in e2e_here]
-    layer = [load_metric(m["name"], m["unit"], metrics_dir)
-             for m in spec["per_layer"] if reported(m, e2e_here)]
-    return Cell(name, int(wl["chips"]), dep, traffic, e2e, layer)
+    per_layer = [load_metric(m["name"], m["unit"], metrics_dir)
+                 for m in spec["per_layer"] if reported(m, e2e_here)]
+    return Cell(name, int(wl["chips"]), dep, traffic, e2e, per_layer, layer)
 
 
 def main(argv=None) -> int:

@@ -1,24 +1,13 @@
-"""A configuration file made into the system under test, from the seed.
+"""What every layer draws from the seed: activations and seed streams.
 
-A configuration (``bench/configs/<name>.json``) states the deployment:
-the private weight's shape (``hidden_size`` x ``intermediate_size`` for an
-up-projection, the reverse for a down-projection), the coded-computing
-scheme, the worker pool and its latency model, and ``max_batch``.  The
-cell's chip count says whether Phase 2 runs across a mesh.  Everything
-random comes from ``--seed``: the weights, made on the device in one
-jitted call; the activations, one stream in submission order; and the
-pool's per-replay traces.
-
-Weights and activations are uniform in [-1, 1).  The engine picks one
-power-of-two fixed-point scale per request so that the product cannot
-wrap mod p; at these contraction depths that scale is 2, so operands
-round to integers in [-2, 2].  Gaussian weights of standard deviation
-1/sqrt(k) would all round to 0 there, and every served ``Y`` would be 0.
+A layer (``bench/layers/<name>.py``) makes its weights, engine and pool
+traces from ``--seed`` through ``_seed32`` and the streams below; the
+harness draws each request's activations from ``Activations``, one
+stream in submission order.  ``Deployment``, ``make_weights`` and
+``make_engine`` of the single projection live in
+``bench/layers/projection.py`` and are re-exported here.
 """
 from __future__ import annotations
-
-from dataclasses import dataclass
-from typing import Any, List
 
 import numpy as np
 
@@ -31,95 +20,24 @@ def _seed32(seed: int, *stream: int) -> int:
     return int(np.random.SeedSequence([seed, *stream]).generate_state(1)[0] >> 1)
 
 
-@dataclass(frozen=True)
-class Deployment:
-    """The parts of a configuration file the harness acts on."""
-
-    name: str
-    k: int
-    out: int
-    method: str
-    s: int
-    t: int
-    z: int
-    n_spare: int
-    p: int
-    max_batch: int
-    latency_shift: float
-    latency_scale: float
-    net_scale: float
-    n_traces: int
-
-    @classmethod
-    def from_dict(cls, d: dict) -> "Deployment":
-        proj = d["projection"]
-        if proj == "up":
-            k, out = d["hidden_size"], d["intermediate_size"]
-        elif proj == "down":
-            k, out = d["intermediate_size"], d["hidden_size"]
-        else:
-            raise ValueError(f"unknown projection {proj!r}")
-        sch, pool = d["scheme"], d["pool"]
-        return cls(
-            name=d["name"], k=int(k), out=int(out), method=sch["method"],
-            s=int(sch["s"]), t=int(sch["t"]), z=int(sch["z"]),
-            n_spare=int(pool["spares"]), p=int(d["field_p"]),
-            max_batch=int(d["max_batch"]),
-            latency_shift=float(pool["compute_latency"]["shift"]),
-            latency_scale=float(pool["compute_latency"]["scale"]),
-            net_scale=float(pool["net_scale"]), n_traces=int(pool["traces"]),
-        )
-
-
 class Activations:
-    """Request activations ``[rows, k]`` in submission order, from the seed."""
+    """Request activations ``[rows, width]`` in submission order, from the seed."""
 
-    def __init__(self, seed: int, rows: int, k: int, stream: int = _X):
+    def __init__(self, seed: int, rows: int, width: int, stream: int = _X):
         self._rng = np.random.default_rng(np.random.SeedSequence([seed, stream]))
-        self.rows, self.k = rows, k
+        self.rows, self.width = rows, width
 
     def next(self) -> np.ndarray:
-        return self._rng.uniform(-1.0, 1.0, size=(self.rows, self.k))
+        return self._rng.uniform(-1.0, 1.0, size=(self.rows, self.width))
 
 
-def make_weights(dep: Deployment, seed: int) -> np.ndarray:
-    """``W [k, out]`` made on the device in one call, as the float64 host
-    array the engine serves from."""
-    import jax
-
-    key = jax.random.PRNGKey(_seed32(seed, _W))
-    gen = jax.jit(lambda k: jax.random.uniform(k, (dep.k, dep.out), minval=-1.0, maxval=1.0))
-    return np.asarray(gen(key), np.float64)
+_PROJECTION = ("Deployment", "make_weights", "make_engine")
 
 
-def make_engine(dep: Deployment, w: np.ndarray, seed: int, devices: List[Any]):
-    """The system under test: a ``ServingEngine`` over the seeded pool,
-    with Phase 2 across a ``workers`` mesh of ``devices`` when there are
-    more than one.
+def __getattr__(name: str):
+    # loaded on first use: bench.layers.projection imports this module
+    if name in _PROJECTION:
+        from bench.layers import projection
 
-    The simulated clock neither sheds nor defers (no SLO, admission off),
-    so the host clock alone times a request, and the engine's ``validate``
-    oracle stays off: it is not part of the served path.
-    """
-    from repro.core.constructions import PlanConfig
-    from repro.core.gf import Field
-    from repro.runtime.pool import ShiftedExponential, sample_trace
-    from repro.serve import ServingEngine
-
-    cfg = PlanConfig(dep.method, dep.s, dep.t, dep.z)
-    pool = cfg.n_workers + dep.n_spare
-    latency = ShiftedExponential(dep.latency_shift, dep.latency_scale)
-    traces = [
-        sample_trace(pool, latency, seed=_seed32(seed, _TRACES, i),
-                     net_scale=dep.net_scale)
-        for i in range(dep.n_traces)
-    ]
-    mesh = None
-    if len(devices) > 1:
-        from jax.sharding import Mesh
-
-        mesh = Mesh(np.array(devices), ("workers",))
-    return ServingEngine(
-        w, traces, cfg, field=Field(dep.p), max_batch=dep.max_batch, admission=False,
-        validate=False, seed=_seed32(seed, _ENGINE), mesh=mesh,
-    )
+        return getattr(projection, name)
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
