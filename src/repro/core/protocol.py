@@ -239,10 +239,8 @@ def degree_reduce(
             jnp.asarray(r_sum.reshape(plan.scheme.z, -1).astype(np.int32)),
             p=p,
         )
-        i_evals = (
-            i_flat.astype(jnp.uint32) + noise_flat.astype(jnp.uint32)
-        ) % jnp.uint32(p)
-        return i_evals.astype(jnp.int32).reshape((plan.n_total,) + blk)
+        i_evals = _add_mod_i32(i_flat, noise_flat, p)
+        return i_evals.reshape((plan.n_total,) + blk)
 
 
 # ----------------------------------------------------------------------
@@ -664,6 +662,16 @@ def _mod_i32(x: jnp.ndarray, p: int) -> jnp.ndarray:
     # ([8, 2304, 5760] int32), a remainder by a constant compiles in
     # about 1 s and runs in 2.1 ms; by a traced divisor, 35 s and 7.9 ms
     return jnp.mod(x, p).astype(jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnums=2)
+def _add_mod_i32(x: jnp.ndarray, y: jnp.ndarray, p: int) -> jnp.ndarray:
+    # p static, as in _mod_i32: compiled ahead of time for a v5e at the
+    # degree reduction's shapes, the sum's remainder by a traced divisor
+    # took 17-27 s per shape, by a constant 0.6 s
+    return ((x.astype(jnp.uint32) + y.astype(jnp.uint32)) % jnp.uint32(p)).astype(
+        jnp.int32
+    )
 
 
 def _field_i32(x, p: int) -> jnp.ndarray:

@@ -276,9 +276,13 @@ class PipelineSession:
         *,
         not_before: float = 0.0,
         obs_attrs: Optional[dict] = None,
+        plan: Optional[CMPCPlan] = None,
     ) -> PipelineReplay:
         """Replay one batch (a: [batch, k, ma], b: [batch, k, mb]; 2D
         promotes to batch 1) against ``trace`` at the live occupancy.
+        ``plan`` serves this replay in place of the session's own (one
+        session, one pool, several operand shapes); it must provision
+        the same pool.
         Either operand may be a host array or a ``jax.Array`` already on
         the device; a device operand is reduced mod p where it lives.
 
@@ -297,13 +301,13 @@ class PipelineSession:
         int32 size of the host operands alone).
         """
         with TRACER.span("runtime.replay", replay=len(self._replays)):
-            return self._append(a, b, trace, not_before, obs_attrs)
+            return self._append(a, b, trace, not_before, obs_attrs, plan)
 
-    def _append(self, a, b, trace, not_before, obs_attrs) -> PipelineReplay:
+    def _append(self, a, b, trace, not_before, obs_attrs, plan) -> PipelineReplay:
         k = len(self._replays)
         if self.planner is None:
             decision = None
-            plan_k = self.plan
+            plan_k = self.plan if plan is None else plan
             if trace.n != plan_k.n_total:
                 raise ValueError(
                     f"trace {k} covers {trace.n} workers, plan provisions "

@@ -20,6 +20,7 @@ Everything downstream of ``submit()`` is deterministic per seed —
 arrivals, traces, admission, and every published percentile.
 """
 from .engine import ServingEngine  # noqa: F401
+from .moe import MoEConfig, MoERequest, PrivateMoE  # noqa: F401
 from .request import (  # noqa: F401
     ADMITTED,
     DONE,

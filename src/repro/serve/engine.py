@@ -56,7 +56,7 @@ from __future__ import annotations
 import dataclasses
 import itertools
 import math
-from typing import List, Optional, Sequence, Union
+from typing import Dict, List, Mapping, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -71,7 +71,7 @@ from ..runtime.metrics import estimate_pool, observed_run
 from ..runtime.pipeline import PipelineRun, PipelineSession
 from ..runtime.pool import ElasticPool, WorkerTrace
 from ..runtime.scheduler import DEFAULT_SUBSET_TRIES, HybridState
-from .request import DONE, SHED, EngineReport, Request
+from .request import DEFAULT_WEIGHT, DONE, SHED, EngineReport, Request
 
 TraceSource = Union[WorkerTrace, ElasticPool, Sequence[WorkerTrace]]
 
@@ -96,16 +96,33 @@ def _trace_list(traces: TraceSource) -> List[WorkerTrace]:
 
 
 class ServingEngine:
-    """Request queue + continuous batcher over one private weight matrix.
+    """Request queue + continuous batcher over private weight matrices.
 
-    ``w``: [k, out] — the layer owner's private operand (every request
-    multiplies against it; per-request fixed-point scales are chosen
-    from each request's own activation range, so one engine serves
-    requests of very different magnitudes exactly).  ``W`` never
-    changes, so its int32 field residues stay on the device, one array
-    per fixed-point scale, uploaded on the scale's first request; each
+    ``w``: one [k, out] matrix, or ``{name: [k, out]}`` — the layer
+    owner's private operands; a request names the one it multiplies
+    against (``submit(..., weight=name)``; an engine of one matrix
+    calls it ``"w"``).  Per-request fixed-point scales are chosen from
+    each request's own activation range, so one engine serves requests
+    of very different magnitudes exactly.  The weights never change, so
+    their int32 field residues stay on the device, one array per
+    ``(name, scale)``, uploaded on that pair's first request; each
     replay stacks its B operand on the device from them, and only the
-    encoded activations cross from the host.
+    encoded activations cross from the host.  All weights share one
+    worker pool, one pipeline session and one planner.
+
+    Rows: ``row_buckets`` is a ladder of row counts, each divisible by
+    ``t``; without one, the first request's row count is the ladder's
+    one rung.  A request of any count up to the largest rung is padded
+    with zero rows to the smallest rung that holds it, and the padding
+    is stripped after decode.  A replay batches requests of one shape
+    class: one weight shape ``(k, out)`` and one rung.  It holds at
+    most ``max_batch`` requests and, under ``max_rows``, no more rows
+    than that, but always one request (``batch_cap``): ``max_rows``
+    bounds a replay's device memory.  A replay's batch pads with zero
+    entries, whose answers are dropped, to the smallest batch the
+    engine has already launched for its shape class, whose program is
+    compiled (``launch_batch``); so once set-up has launched a ladder
+    of batch sizes, the window compiles nothing.
 
     Usage: ``submit()`` requests (simulated arrival stamps), then one
     ``run()`` to drain the queue; ``report.requests`` carries each
@@ -115,7 +132,7 @@ class ServingEngine:
 
     def __init__(
         self,
-        w: np.ndarray,
+        w: Union[np.ndarray, Mapping[str, np.ndarray]],
         traces: TraceSource,
         config: Optional[PlanConfig] = None,
         *,
@@ -140,14 +157,22 @@ class ServingEngine:
         planner=None,
         plan_seed: int = 0,
         validate: bool = False,
+        row_buckets: Optional[Sequence[int]] = None,
+        max_rows: Optional[int] = None,
     ):
         if mode not in ("continuous", "boundary"):
             raise ValueError(f"mode must be 'continuous' or 'boundary', got {mode!r}")
         if max_batch < 1:
             raise ValueError(f"max_batch must be >= 1, got {max_batch}")
-        self.w = np.asarray(w, np.float64)
-        if self.w.ndim != 2:
-            raise ValueError(f"w must be [k, out], got {self.w.shape}")
+        named = w if isinstance(w, Mapping) else {DEFAULT_WEIGHT: w}
+        if not named:
+            raise ValueError("need at least one weight matrix")
+        self.weights: Dict[str, np.ndarray] = {
+            name: np.asarray(m, np.float64) for name, m in named.items()
+        }
+        for name, m in self.weights.items():
+            if m.ndim != 2:
+                raise ValueError(f"weight {name!r} must be [k, out], got {m.shape}")
         self.config = config or PlanConfig()
         self.field = field or Field()
         self.seed = seed
@@ -178,21 +203,36 @@ class ServingEngine:
         )
         self._decode_mode = decode_mode
         self._plan_seed = plan_seed
-        k, out = self.w.shape
-        if k % self.config.s:
-            raise ValueError(
-                f"s={self.config.s} must divide w's inner dim k={k}"
-            )
-        if out % self.config.t:
-            raise ValueError(
-                f"t={self.config.t} must divide w's output dim {out}"
-            )
+        for name, m in self.weights.items():
+            k, out = m.shape
+            if k % self.config.s:
+                raise ValueError(
+                    f"s={self.config.s} must divide {name}'s inner dim k={k}"
+                )
+            if out % self.config.t:
+                raise ValueError(
+                    f"t={self.config.t} must divide {name}'s output dim {out}"
+                )
+        # None until the first request, whose row count is then the one rung
+        self.row_buckets: Optional[Tuple[int, ...]] = None
+        if row_buckets is not None:
+            self.row_buckets = tuple(sorted({int(b) for b in row_buckets}))
+            bad = [b for b in self.row_buckets if b < 1 or b % self.config.t]
+            if not self.row_buckets or bad:
+                raise ValueError(
+                    f"row buckets must be positive multiples of t={self.config.t}, "
+                    f"got {list(row_buckets)}"
+                )
+        self.max_rows = max_rows
 
         self._traces = _trace_list(traces)
         self._t_idx = 0
-        self._rows: Optional[int] = None  # per-request row count, fixed
-        self._w_max = float(np.abs(self.w).max() + 1e-9)
-        self._w_dev: dict = {}  # scale -> W's int32 residues on the device
+        self._w_max = {
+            name: float(np.abs(m).max() + 1e-9) for name, m in self.weights.items()
+        }
+        self._w_dev: dict = {}  # (name, scale) -> int32 residues on the device
+        self.rows_served = 0  # request rows launched, across replays
+        self.rows_launched = 0  # rows launched, bucket and batch padding included
         self._queue: List[Request] = []
         self._all: List[Request] = []
         self._next_rid = 0
@@ -200,6 +240,8 @@ class ServingEngine:
         self._session: Optional[PipelineSession] = None
         self._pool_n: Optional[int] = None
         self._cfg_fit: Optional[PlanConfig] = None
+        self._plans: dict = {}  # shape class -> plan of the fitted config
+        self._launched: dict = {}  # shape class -> batch sizes launched (compiled)
         self._clock = 0.0  # reconfiguration barrier carries across sessions
         self._replays_total = 0  # across sessions/reconfigurations
 
@@ -210,29 +252,49 @@ class ServingEngine:
         x: np.ndarray,
         arrival: float,
         deadline: Optional[float] = None,
+        weight: Optional[str] = None,
     ) -> Request:
-        """Queue one request: ``x`` [rows, k] activation rows arriving
-        at simulated time ``arrival``.  ``deadline`` is absolute; when
-        ``None`` and the engine has an ``slo``, it defaults to
-        ``arrival + slo``.  Returns the live :class:`Request` record
-        (mutated in place as the engine serves it)."""
+        """Queue one request: ``x`` [rows, k] activation rows against
+        weight ``weight`` (may be omitted when the engine holds one),
+        arriving at simulated time ``arrival``.  ``deadline`` is
+        absolute; when ``None`` and the engine has an ``slo``, it
+        defaults to ``arrival + slo``.  Returns the live
+        :class:`Request` record (mutated in place as the engine serves
+        it).
+
+        Raises ``ValueError`` where no fixed-point scale keeps the
+        product from wrapping mod p: ``k * max|x| * max|w|`` at scale 1
+        already reaches ``(p - 1) / 2``."""
+        if weight is None:
+            if len(self.weights) != 1:
+                raise ValueError(
+                    f"name the weight: this engine holds {sorted(self.weights)}"
+                )
+            (weight,) = self.weights
+        if weight not in self.weights:
+            raise ValueError(f"unknown weight {weight!r} (held: {sorted(self.weights)})")
+        k = self.weights[weight].shape[0]
         x = np.asarray(x, np.float64)
         if x.ndim == 1:
             x = x[None]
-        if x.ndim != 2 or x.shape[1] != self.w.shape[0]:
+        if x.ndim != 2 or x.shape[1] != k:
+            raise ValueError(f"x must be [rows, k={k}], got {x.shape}")
+        rows = int(x.shape[0])
+        if self.row_buckets is None:  # the first request's rows: the one rung
+            if rows < 1 or rows % self.config.t:
+                raise ValueError(f"t={self.config.t} must divide request rows {rows}")
+            self.row_buckets = (rows,)
+        bucket = next((b for b in self.row_buckets if b >= rows), None)
+        if rows < 1 or bucket is None:
             raise ValueError(
-                f"x must be [rows, k={self.w.shape[0]}], got {x.shape}"
+                f"request rows {rows} outside the row buckets {self.row_buckets}"
             )
-        if self._rows is None:
-            if x.shape[0] % self.config.t:
-                raise ValueError(
-                    f"t={self.config.t} must divide request rows {x.shape[0]}"
-                )
-            self._rows = int(x.shape[0])
-        elif x.shape[0] != self._rows:
+        x_max, w_max = float(np.abs(x).max() + 1e-9), self._w_max[weight]
+        if k * x_max * w_max >= (self.field.p - 1) // 2:
             raise ValueError(
-                f"request rows {x.shape[0]} != engine rows {self._rows} "
-                "(one batched plan serves every request)"
+                f"no fixed-point scale fits: k={k} * max|x|={x_max} * "
+                f"max|{weight}|={w_max} >= (p-1)/2 = {(self.field.p - 1) // 2}, "
+                "so the product could wrap mod p"
             )
         if deadline is None and self.slo is not None:
             deadline = float(arrival) + float(self.slo)
@@ -241,6 +303,9 @@ class ServingEngine:
             x=x,
             arrival=float(arrival),
             deadline=deadline,
+            weight=weight,
+            bucket=bucket,
+            scale=choose_scales(k, x_max, w_max, self.field.p),
         )
         self._next_rid += 1
         self._queue.append(req)
@@ -304,15 +369,22 @@ class ServingEngine:
         """FIFO admission over requests already arrived at ``t_launch``,
         shedding hopeless deadlines and halving the cap while the pool
         estimates disagree (degraded => defer the tail to later
-        launches).  Mutates the queue; returns the admitted batch."""
+        launches).  A replay holds one shape class: the first arrived
+        request's, and the others wait their turn.  Mutates the queue;
+        returns the admitted batch."""
         candidates = [r for r in self._queue if r.arrival <= t_launch + 1e-12]
+        limit = self.max_batch
+        if candidates:
+            head = self._shape_class(candidates[0])
+            candidates = [r for r in candidates if self._shape_class(r) == head]
+            limit = self.batch_cap(head[2])
         if not self.admission:
-            batch = candidates[: self.max_batch]
+            batch = candidates[:limit]
             for r in batch:
                 self._queue.remove(r)
             return batch
         predicted, degraded = self._predicted_service()
-        cap = self.max_batch if not degraded else max(1, self.max_batch // 2)
+        cap = limit if not degraded else max(1, limit // 2)
         admitted: List[Request] = []
         for r in candidates:
             if len(admitted) == cap:
@@ -328,6 +400,24 @@ class ServingEngine:
             self._queue.remove(r)
             admitted.append(r)
         return admitted
+
+    def _shape_class(self, r: Request) -> tuple:
+        """What one compiled replay program serves: ``(k, out, rows)``."""
+        return self.weights[r.weight].shape + (r.bucket,)
+
+    def batch_cap(self, rows: int) -> int:
+        """Requests a replay of ``rows``-row requests holds at most:
+        ``max_batch``, and under ``max_rows`` no more than fit in it, but
+        at least one."""
+        if self.max_rows is None:
+            return self.max_batch
+        return max(1, min(self.max_batch, self.max_rows // rows))
+
+    def launch_batch(self, n: int, shape_class: tuple) -> int:
+        """The batch a replay of ``n`` requests of ``shape_class``
+        launches at: the smallest this engine has launched for the class
+        that holds ``n``, or ``n`` itself."""
+        return min((b for b in self._launched.get(shape_class, ()) if b >= n), default=n)
 
     # -- session / pool management --------------------------------------
 
@@ -356,19 +446,25 @@ class ServingEngine:
                 **self._session_kw,
             )
         else:
-            plan = self._plan_for(cfg)
+            self._plans = {}
+            plan = self._plan_for(self._shape_class(self._queue[0]))
             self._session = PipelineSession(
                 plan, seed=self.seed, base_time=self._clock,
                 hybrid_state=hybrid, **self._session_kw,
             )
         return True
 
-    def _plan_for(self, cfg: PlanConfig) -> CMPCPlan:
-        k, out = self.w.shape
-        shapes = BlockShapes(
-            k=k, ma=self._rows, mb=out, s=cfg.s, t=cfg.t
-        )
-        return get_plan_for(cfg, shapes, field=self.field, seed=self._plan_seed)
+    def _plan_for(self, shape_class: tuple) -> CMPCPlan:
+        """The fitted config's plan for one shape class, looked up once
+        per session."""
+        if shape_class not in self._plans:
+            k, out, rows = shape_class
+            cfg = self._cfg_fit
+            shapes = BlockShapes(k=k, ma=rows, mb=out, s=cfg.s, t=cfg.t)
+            self._plans[shape_class] = get_plan_for(
+                cfg, shapes, field=self.field, seed=self._plan_seed
+            )
+        return self._plans[shape_class]
 
     # -- the batcher loop ------------------------------------------------
 
@@ -376,7 +472,6 @@ class ServingEngine:
         """Drain the queue: admit, launch, decode, account.  Returns the
         :class:`EngineReport`; every submitted request ends ``done`` or
         ``shed`` — a drained queue leaves nothing in flight."""
-        k_dim, out = self.w.shape
         with TRACER.span("serve.run", requests=len(self._queue)):
             while self._queue:
                 with TRACER.span("serve.admit") as sp:
@@ -398,48 +493,54 @@ class ServingEngine:
                 if not batch:
                     continue  # everything eligible was shed; queue shrank
                 self._t_idx += 1
+                shape_class = self._shape_class(batch[0])
+                k, _, rows = shape_class
+                launched = self.launch_batch(len(batch), shape_class)
                 with TRACER.span("serve.encode", replay=self._session.depth) as sp:
-                    scales = [
-                        choose_scales(
-                            k_dim, float(np.abs(r.x).max() + 1e-9), self._w_max,
-                            self.field.p,
+                    aq = np.zeros((launched, k, rows), np.int64)  # padding stays 0
+                    for i, r in enumerate(batch):
+                        aq[i, :, : r.x.shape[0]] = self.field.encode(r.x.T, r.scale)
+                    keys = [(r.weight, r.scale) for r in batch]
+                    keys += keys[:1] * (launched - len(batch))
+                    new = set(keys) - self._w_dev.keys()
+                    for name, s in new:
+                        self._w_dev[name, s] = jax.device_put(
+                            self.field.encode(self.weights[name], s).astype(np.int32)
                         )
-                        for r in batch
-                    ]
-                    aq = np.stack([
-                        self.field.encode(r.x.T, s) for r, s in zip(batch, scales)
-                    ])  # [batch, k, rows]
-                    new = set(scales) - self._w_dev.keys()
-                    for s in new:
-                        self._w_dev[s] = jax.device_put(
-                            self.field.encode(self.w, s).astype(np.int32)
-                        )
-                    bq = _stack([self._w_dev[s] for s in scales])  # [batch, k, out]
+                    bq = _stack([self._w_dev[key] for key in keys])  # [launched, k, out]
                     sp.set(
                         bytes=int(aq.nbytes + bq.nbytes),
                         requests=len(batch),
                         w_hits=len(batch) - len(new),
-                        w_bytes=sum(int(self._w_dev[s].nbytes) for s in new),
+                        w_bytes=sum(int(self._w_dev[key].nbytes) for key in new),
                     )
+                self.rows_served += sum(int(r.x.shape[0]) for r in batch)
+                self.rows_launched += launched * rows
+                self._launched.setdefault(shape_class, set()).add(launched)
                 replay = self._session.append(
                     aq, bq, trace, not_before=t_launch,
                     obs_attrs={"n_requests": len(batch)},
+                    plan=(
+                        None if self.planner is not None
+                        else self._plan_for(shape_class)
+                    ),
                 )
                 self._obs.append(observed_run(replay.metrics, start=replay.start))
                 self._replays_total += 1
                 with TRACER.span("serve.decode", replay=replay.index):
-                    yq = np.asarray(replay.y)  # [batch, rows, out] field values
-                    for i, (r, s) in enumerate(zip(batch, scales)):
+                    yq = np.asarray(replay.y)  # [launched, rows, out] field values
+                    for i, r in enumerate(batch):
+                        s = r.scale
                         if self.validate:
                             want = self.field.matmul(
-                                aq[i].T, self.field.encode(self.w, s)
+                                aq[i].T, self.field.encode(self.weights[r.weight], s)
                             )
                             if not np.array_equal(yq[i], want):
                                 raise AssertionError(
                                     f"request {r.rid}: decode disagrees with the "
                                     f"field oracle on replay {replay.index}"
                                 )
-                        r.y = self.field.decode(yq[i], s * s)
+                        r.y = self.field.decode(yq[i, : r.x.shape[0]], s * s)
                         r.state = DONE
                         r.launch = replay.start
                         r.completion = replay.completion
@@ -469,6 +570,13 @@ class ServingEngine:
             replays=self._replays_total,
             makespan=makespan,
         )
+
+    def forget(self, requests: Sequence[Request]) -> None:
+        """Drop served requests from the record ``report()`` reads, so a
+        caller that has taken their answers leaves the engine holding
+        none of its arrays."""
+        gone = {id(r) for r in requests}
+        self._all = [r for r in self._all if id(r) not in gone]
 
     def pipeline_result(self) -> PipelineRun:
         """The underlying session's :class:`PipelineRun` (current pool's

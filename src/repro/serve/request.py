@@ -23,6 +23,9 @@ from typing import List, Optional
 
 import numpy as np
 
+#: The name of the weight of an engine built from one matrix.
+DEFAULT_WEIGHT = "w"
+
 #: Request lifecycle states.
 QUEUED = "queued"
 ADMITTED = "admitted"
@@ -32,7 +35,7 @@ SHED = "shed"
 
 @dataclasses.dataclass
 class Request:
-    """One secure-matmul request against the engine's weight matrix."""
+    """One secure-matmul request against one of the engine's weight matrices."""
 
     rid: int
     x: np.ndarray  # [rows, k] activation rows (source-1 operand)
@@ -44,6 +47,9 @@ class Request:
     replay: int = -1  # session replay index that served it
     shed_reason: Optional[str] = None
     y: Optional[np.ndarray] = None  # [rows, out] decoded activations
+    weight: str = DEFAULT_WEIGHT  # the engine weight it multiplies against
+    bucket: int = 0  # rows it launches at, padding included
+    scale: int = 1  # its fixed-point scale, fixed at submit
 
     @property
     def latency(self) -> float:

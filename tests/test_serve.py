@@ -497,7 +497,7 @@ def test_resident_w_decodes_bit_identically_to_host_stack(monkeypatch, mags, max
                       float(np.abs(w).max() + 1e-9), FIELD.p)
         for r in reqs
     }
-    assert sorted(eng._w_dev) == sorted(scales)
+    assert sorted(eng._w_dev) == sorted(("w", s) for s in scales)
     assert len(scales) == (4 if len(set(mags)) > 1 else 1)
     w_bytes = K_DIM * OUT * 4  # W's int32 residues at one scale
     assert sum(a["requests"] for a in attrs) == len(mags)

@@ -118,6 +118,25 @@ def test_matmul_site_compiles_for_v5e(site, plan, one_chip):
     assert _total_bytes(compiled) < HBM_BYTES
 
 
+# DeepSeek-V3's MoE layer at its published widths (hidden 7168, expert
+# width 2048) under the same scheme: the worker products and the Phase-2
+# mix of its largest replays, 8 products of a 128-row bucket (the held
+# experts) and one of 2048 rows (the shared expert's down product).
+MOE_SITES = {
+    "gate_up_multiply": ((8, 21, 64, 3584), (8, 21, 3584, 1024)),
+    "down_multiply": ((8, 21, 64, 1024), (8, 21, 1024, 3584)),
+    "shared_down_mix": ((21, 17), (17, 1024 * 3584)),
+}
+
+
+@pytest.mark.parametrize("site", sorted(MOE_SITES))
+def test_moe_matmul_site_compiles_for_v5e(site, one_chip):
+    sa, sb = MOE_SITES[site]
+    compiled = _compile_matmul(sa, sb, one_chip, backend="pallas")
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _total_bytes(compiled) < HBM_BYTES
+
+
 def test_share_program_fits_v5e(plan, one_chip, monkeypatch):
     """Phase 1 at full width is the largest program on the path.  With
     the share stack's K = 6 padded to 128 it needed ~19 GB."""
