@@ -577,6 +577,50 @@ def _decode_batched_jit(
     return y_blocks.transpose(0, 2, 3, 1, 4).reshape(batch, t * bry, t * bcy)
 
 
+@functools.partial(jax.jit, static_argnames=("p", "backend"))
+def _decode_subset_jit(
+    i_evals: jnp.ndarray,
+    decode_rows: jnp.ndarray,
+    ids: jnp.ndarray,
+    *,
+    p: int,
+    backend: str,
+) -> jnp.ndarray:
+    """Phase 3 on device for one responder subset: the given rows of its
+    decode matrix times the subset's I-evaluations.
+
+    i_evals: [n_total, ...]; returns [rows, payload] int32, the payload
+    flattened in the order of ``i_evals.reshape(n_total, -1)``.
+    """
+    sel = jnp.take(i_evals.reshape(i_evals.shape[0], -1), ids, axis=0)
+    return mod_matmul(decode_rows, sel, p=p, backend=backend)
+
+
+def decode_y_coeffs(
+    plan: CMPCPlan, i_evals: jnp.ndarray, worker_ids: Sequence[int],
+    backend: str = "auto",
+) -> jnp.ndarray:
+    """Launch the decode of Y's coefficients from a responder subset.
+
+    ``i_evals`` is the device-resident ``[n_total, ...]`` I-evaluations,
+    ``worker_ids`` ``decode_threshold`` sorted responder ids.  Returns the
+    first t^2 coefficients of I(x) — Y's blocks, the rest is blinding — as
+    an int32 ``[t^2, payload]`` device array, bit-identical to the same
+    rows of ``field.matmul(decode_matrix_cached(ids), evals[ids])``.  The
+    subset's device ids and int32 decode rows are cached on the plan next
+    to its host decode matrix, so a recurring subset uploads nothing.
+    """
+    p = plan.field.p
+    t2 = plan.scheme.t ** 2
+
+    def build(ids: np.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+        rows = plan.decode_matrix_cached(ids)[:t2] % p
+        return jnp.asarray(ids.astype(np.int32)), jnp.asarray(rows.astype(np.int32))
+
+    ids_dev, rows_dev = plan._subset_cached("dec_dev", np.asarray(worker_ids), build)
+    return _decode_subset_jit(i_evals, rows_dev, ids_dev, p=p, backend=backend)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(

@@ -17,7 +17,10 @@ module decides *which subset actually does*, by replaying a
 3. responses stream back to the master; decode triggers as soon as the
    fastest ``decode_threshold`` responders are in (the per-subset
    decode matrix comes from the plan's subset cache, so recurring
-   fastest-subsets cost one Gauss-Jordan total).
+   fastest-subsets cost one Gauss-Jordan total).  When that decode
+   asks no confirmations and the I-evaluations are on the device, it
+   runs there and only Y's t^2 coefficient rows reach the host
+   (``_device_decode``); every other decode is the host's.
 
 Corrupted responses — two strategies, picked by ``decode_mode``:
 
@@ -141,7 +144,9 @@ DEFAULT_SUBSET_TRIES = 128
 class _Replay:
     """Everything the event loop decided for one trace replay."""
 
-    coeffs: np.ndarray  # [thr, payload] interpolated I(x) coefficients
+    # interpolated I(x) coefficients: [thr, payload] from the host's
+    # decode, Y's [t^2, payload] alone from the device's
+    coeffs: np.ndarray
     phase2_ids: np.ndarray
     responder_ids: np.ndarray
     confirmed_by: np.ndarray
@@ -324,7 +329,8 @@ def _replay_events(
     link_starved: list = []  # receivers with a dead incoming link
     phase2_ids: Optional[np.ndarray] = None
     phase2_set_time = float("nan")
-    i_all: Optional[np.ndarray] = None
+    i_all: Optional[np.ndarray] = None  # host copy, when the host decodes
+    device_decode = False
     vander_check: Optional[np.ndarray] = None
     arrived: list = []  # (time, worker) in response-arrival order
     first_response = float("nan")
@@ -353,20 +359,35 @@ def _replay_events(
             # canonical subset-cache key).
             phase2_ids = np.sort(np.array(computed))
             phase2_set_time = t_now
-            # Waiting for the device and copying its result to the host
-            # are timed apart.  np.array (not asarray): device outputs are
-            # read-only views and corrupt rows are overwritten below.
             i_dev = compute_i_all(phase2_ids)
-            with TRACER.span("runtime.device_wait", replay=replay):
-                jax.block_until_ready(i_dev)
-            with TRACER.span("runtime.fetch", replay=replay) as sp:
-                i_all = np.array(i_dev)
-                sp.set(bytes=int(i_all.nbytes))
-            # Corrupt workers respond with garbage of the right shape
-            # (garbage spans their whole payload — every product of a
-            # batched replay sees the same worker corrupt).
-            for c in np.flatnonzero(trace.corrupt & alive):
-                i_all[c] = rng.integers(0, p, size=i_all[c].shape, dtype=np.int64)
+            # Without confirmations a detect decode accepts the fastest
+            # decode_threshold responders as they are, so when the
+            # I-evaluations live on the device and no live worker is
+            # corrupt (its garbage is drawn on the host, from rng), that
+            # decode runs on the device and only Y's coefficients come
+            # back (_device_decode).  Otherwise the host decodes.
+            device_decode = (
+                decode_mode == "detect"
+                and verify_extras == 0
+                and isinstance(i_dev, jax.Array)
+                and not (trace.corrupt & alive).any()
+            )
+            if not device_decode:
+                # Waiting for the device and copying its result to the
+                # host are timed apart.  np.array (not asarray): device
+                # outputs are read-only views and corrupt rows are
+                # overwritten below.
+                with TRACER.span("runtime.device_wait", replay=replay):
+                    jax.block_until_ready(i_dev)
+                with TRACER.span("runtime.fetch", replay=replay, decode="host") as sp:
+                    i_all = np.array(i_dev)
+                    sp.set(bytes=int(i_all.nbytes))
+                REGISTRY.counter("runtime.host_decodes").inc()
+                # Corrupt workers respond with garbage of the right shape
+                # (garbage spans their whole payload — every product of a
+                # batched replay sees the same worker corrupt).
+                for c in np.flatnonzero(trace.corrupt & alive):
+                    i_all[c] = rng.integers(0, p, size=i_all[c].shape, dtype=np.int64)
             vander_check = plan.decode_check_matrix()
             # Live, non-crashed workers respond one exchange + uplink
             # delay after the set is announced.  With a link matrix the
@@ -440,10 +461,13 @@ def _replay_events(
             ))
         if len(arrived) < plan.decode_threshold + verify_extras:
             continue
-        accepted = _try_decode(
-            plan, i_all, arrived, verify_extras, vander_check, rng,
-            decode_cache, max_subset_tries,
-        )
+        if device_decode:
+            accepted = _device_decode(plan, i_dev, arrived, replay)
+        else:
+            accepted = _try_decode(
+                plan, i_all, arrived, verify_extras, vander_check, rng,
+                decode_cache, max_subset_tries,
+            )
         if accepted is None:
             continue
         coeffs, responder_ids, confirmed_by, rejected = accepted
@@ -878,6 +902,28 @@ def _candidate_subsets(
     while n < max_tries:
         yield tuple(np.sort(rng.choice(k, size=thr, replace=False)))
         n += 1
+
+
+def _device_decode(
+    plan: CMPCPlan, i_dev: jax.Array, arrived: list, replay
+) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
+    """Decode the fastest ``decode_threshold`` responders on the device.
+
+    The subset is the first candidate of ``_try_decode``'s search, which
+    a decode asking no confirmations accepts at once; it depends on the
+    trace alone, not on the payload.  Only Y's t^2 coefficient rows are
+    copied back.  Returns ``_try_decode``'s tuple.
+    """
+    ids = np.sort(np.array([w for _, w in arrived[: plan.decode_threshold]]))
+    with TRACER.span("protocol.phase3.device_decode", replay=replay):
+        y_dev = proto.decode_y_coeffs(plan, i_dev, ids)
+    with TRACER.span("runtime.device_wait", replay=replay):
+        jax.block_until_ready(y_dev)
+    with TRACER.span("runtime.fetch", replay=replay, decode="device") as sp:
+        coeffs = np.asarray(y_dev)
+        sp.set(bytes=int(coeffs.nbytes))
+    REGISTRY.counter("runtime.device_decodes").inc()
+    return coeffs.astype(np.int64), ids, _EMPTY_IDS.copy(), _EMPTY_IDS.copy()
 
 
 def _try_decode(

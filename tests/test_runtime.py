@@ -350,3 +350,153 @@ def test_summarize(setup):
     assert 1 <= agg["decode_subsets_distinct"] <= 3
     assert agg["n_provisioned"] == plan.n_total
     assert summarize([]) == {"runs": 0}
+
+
+# ----------------------------------------------------------------------
+# Phase 3 on the device: which replays decode there, and that it changes
+# nothing but where the product runs
+# ----------------------------------------------------------------------
+def _decode_counts():
+    from repro.obs import REGISTRY
+
+    return (
+        REGISTRY.counter("runtime.device_decodes").value,
+        REGISTRY.counter("runtime.host_decodes").value,
+    )
+
+
+def _force_host_decode(monkeypatch):
+    """Hand the event loop host copies of the I-evaluations, as the mesh
+    path does: every replay then decodes on the host."""
+    import repro.runtime.pipeline as pipeline
+    import repro.runtime.scheduler as scheduler
+    from repro.core import protocol as proto
+
+    real_closure = scheduler._batched_compute_closure
+    real_reduce = proto.degree_reduce
+
+    def closure(*args, **kw):
+        compute = real_closure(*args, **kw)
+        return lambda ids: np.asarray(compute(ids))
+
+    monkeypatch.setattr(scheduler, "_batched_compute_closure", closure)
+    monkeypatch.setattr(pipeline, "_batched_compute_closure", closure)
+    monkeypatch.setattr(
+        proto, "degree_reduce", lambda *a, **kw: np.asarray(real_reduce(*a, **kw))
+    )
+
+
+def _replays(entry, plan, a1, b1, traces):
+    """(ys, metrics) of each replay of ``traces`` through one entry point."""
+    from repro.runtime import PipelineSession
+
+    if entry == "run_over_pool":
+        runs = [run_over_pool(plan, a1, b1, tr, seed=41 + i) for i, tr in enumerate(traces)]
+        return [r.y for r in runs], [r.metrics for r in runs]
+    a, b, _ = _batch_operands(plan, 3, seed=42)
+    if entry == "run_batch_over_pool":
+        runs = [
+            run_batch_over_pool(plan, a, b, tr, seed=43 + i) for i, tr in enumerate(traces)
+        ]
+        return [r.y for r in runs], [r.metrics for r in runs]
+    session = PipelineSession(plan, seed=44)
+    reps = [session.append(a, b, tr) for tr in traces]
+    return [r.y for r in reps], [r.metrics for r in reps]
+
+
+@pytest.mark.parametrize("entry", ["run_over_pool", "run_batch_over_pool", "session"])
+def test_device_and_host_decode_give_identical_runs(setup, entry, monkeypatch):
+    """A fault-free detect replay decodes on the device; the host decode
+    of the same replay gives the same Y and the same RunMetrics (times,
+    ids, confirmations, accounting) bit for bit, over stragglers, a
+    dropout and non-prefix responder subsets."""
+    import dataclasses
+
+    from repro.runtime.metrics import RunMetrics
+
+    plan, a1, b1, _ = setup
+    traces = [
+        sample_trace(plan.n_total, ShiftedExponential(1.0, 0.3), seed=45 + i)
+        .with_faults(dropout_ids=[i], straggler_ids=[plan.n_total - 1 - i])
+        for i in range(3)
+    ]
+    before = _decode_counts()
+    ys_dev, ms_dev = _replays(entry, plan, a1, b1, traces)
+    mid = _decode_counts()
+    assert (mid[0] - before[0], mid[1] - before[1]) == (3, 0)
+    _force_host_decode(monkeypatch)
+    ys_host, ms_host = _replays(entry, plan, a1, b1, traces)
+    after = _decode_counts()
+    assert (after[0] - mid[0], after[1] - mid[1]) == (0, 3)
+    assert any(
+        not np.array_equal(m.responder_ids, np.arange(plan.decode_threshold))
+        for m in ms_dev
+    )
+    for y_dev, y_host in zip(ys_dev, ys_host):
+        assert y_dev.dtype == y_host.dtype and np.array_equal(y_dev, y_host)
+    for m_dev, m_host in zip(ms_dev, ms_host):
+        for f in dataclasses.fields(RunMetrics):
+            x, y = getattr(m_dev, f.name), getattr(m_host, f.name)
+            if isinstance(x, np.ndarray):
+                assert x.dtype == y.dtype and np.array_equal(x, y), f.name
+            else:
+                assert x == y, f.name
+
+
+@pytest.mark.parametrize(
+    "case", ["verify_extras", "correct", "hybrid_escalated", "corrupt_live", "mesh"]
+)
+def test_host_decodes_what_the_device_path_cannot(setup, case):
+    """Verification, Berlekamp-Welch (also a hybrid pool after it
+    escalates), a trace with a corrupt live worker, and the mesh path's
+    host array all decode on the host: the fetch copies every worker's
+    I-evaluations, no device decode runs, and Y is the oracle's."""
+    import dataclasses
+
+    import jax
+    from jax.sharding import Mesh
+
+    from repro import obs
+    from repro.runtime import HybridState
+
+    plan, _, _, _ = setup
+    a, b, want = _batch_operands(plan, 2, seed=50)
+    trace = sample_trace(plan.n_total, ShiftedExponential(1.0, 0.3), seed=51)
+    kw = {}
+    if case == "verify_extras":
+        kw = dict(verify_extras=1)
+    elif case == "correct":
+        kw = dict(decode_mode="correct", error_budget=1)
+    elif case == "hybrid_escalated":
+        kw = dict(decode_mode="hybrid", hybrid_state=HybridState(escalated=True))
+    elif case == "mesh":
+        kw = dict(mesh=Mesh(np.array(jax.devices()), ("workers",)))
+    elif case == "corrupt_live":
+        # corrupt, but outside the decode subset and with no fault model:
+        # "auto" asks no confirmation, yet the host draws its garbage
+        clean = run_batch_over_pool(plan, a, b, trace, seed=52)
+        bad = next(
+            w for w in range(plan.n_total) if w not in clean.metrics.responder_ids
+        )
+        corrupt = np.zeros(plan.n_total, bool)
+        corrupt[bad] = True
+        trace = dataclasses.replace(trace, corrupt=corrupt)
+    obs.TRACER.clear()
+    obs.enable()
+    before = _decode_counts()
+    try:
+        res = run_batch_over_pool(plan, a, b, trace, seed=52, **kw)
+    finally:
+        obs.disable()
+    after = _decode_counts()
+    walls = [e for e in obs.TRACER.events if e["clock"] == "wall" and e["kind"] == "span"]
+    obs.TRACER.clear()
+    assert np.array_equal(res.y, want)
+    assert (after[0] - before[0], after[1] - before[1]) == (0, 1)
+    (fetch,) = [e for e in walls if e["name"] == "runtime.fetch"]
+    assert fetch["attrs"]["decode"] == "host"
+    sh = plan.shapes
+    assert fetch["attrs"]["bytes"] >= plan.n_total * 2 * sh.blk_y[0] * sh.blk_y[1] * 4
+    assert not [e for e in walls if e["name"] == "protocol.phase3.device_decode"]
+    if case == "corrupt_live":
+        assert np.array_equal(res.metrics.responder_ids, clean.metrics.responder_ids)

@@ -357,16 +357,21 @@ def test_traced_run_splits_each_replay_into_named_host_spans(monkeypatch):
     engine's admit/encode/decode spans under serve.run, and of the
     runtime.replay; the byte counts are the arrays' own sizes.  W's
     residues already live on the device, so runtime.upload's bytes (what
-    crossed from host to device) are A's alone."""
+    crossed from host to device) are A's alone.  The fault-free pool's
+    decode runs on the device under protocol.phase3.device_decode, so
+    runtime.fetch's bytes (what crossed back) are Y's t^2 coefficient
+    rows, not every worker's I-evaluations."""
     import jax
 
     import repro.runtime.pipeline as pipeline
     from repro.core import protocol as proto
 
-    seen = {"encode": [], "upload": [], "fetch": [], "b_on_device": []}
+    seen = {"encode": [], "upload": [], "i_evals": [], "fetch": [], "rows": [],
+            "b_on_device": []}
     real_append = pipeline.PipelineSession.append
     real_prep = proto._prep_batched_operands
     real_closure = pipeline._batched_compute_closure
+    real_decode = proto.decode_y_coeffs
 
     def append(self, a, b, *args, **kw):
         seen["encode"].append(a.nbytes + b.nbytes)
@@ -383,14 +388,21 @@ def test_traced_run_splits_each_replay_into_named_host_spans(monkeypatch):
 
         def recorded(ids):
             out = compute(ids)
-            seen["fetch"].append(out.nbytes)
+            seen["i_evals"].append(out.nbytes)
             return out
 
         return recorded
 
+    def decode(plan, i_evals, ids, *args, **kw):
+        out = real_decode(plan, i_evals, ids, *args, **kw)
+        seen["fetch"].append(out.nbytes)
+        seen["rows"].append((plan.scheme.t ** 2, plan.n_total))
+        return out
+
     monkeypatch.setattr(pipeline.PipelineSession, "append", append)
     monkeypatch.setattr(proto, "_prep_batched_operands", prep)
     monkeypatch.setattr(pipeline, "_batched_compute_closure", closure)
+    monkeypatch.setattr(proto, "decode_y_coeffs", decode)
     TRACER.clear()
     TRACER.enable()
     try:
@@ -413,13 +425,23 @@ def test_traced_run_splits_each_replay_into_named_host_spans(monkeypatch):
     for name in ("serve.admit", "serve.encode", "serve.decode"):
         assert [e["attrs"]["replay"] for e in by_name[name]] == [0, 1], name
         assert all(e["parent"] == run["id"] for e in by_name[name]), name
-    for name in ("runtime.upload", "protocol.phase2", "runtime.device_wait", "runtime.fetch"):
+    for name in (
+        "runtime.upload", "protocol.phase2", "protocol.phase3.device_decode",
+        "runtime.device_wait", "runtime.fetch",
+    ):
         spans = by_name[name]
         assert [e["attrs"]["replay"] for e in spans] == [0, 1], name
         assert [e["parent"] for e in spans] == [e["id"] for e in replays], name
     assert [e["attrs"]["bytes"] for e in by_name["serve.encode"]] == seen["encode"]
     assert [e["attrs"]["bytes"] for e in by_name["runtime.upload"]] == seen["upload"]
+    # the device decodes: what crosses back is Y's t^2 coefficient rows,
+    # not every worker's I-evaluations, and no host search runs
     assert [e["attrs"]["bytes"] for e in by_name["runtime.fetch"]] == seen["fetch"]
+    assert [e["attrs"]["decode"] for e in by_name["runtime.fetch"]] == ["device"] * 2
+    assert [f * n_total for f, (_, n_total) in zip(seen["fetch"], seen["rows"])] == [
+        i * t2 for i, (t2, _) in zip(seen["i_evals"], seen["rows"])
+    ]
+    assert "protocol.phase3.subset_search" not in by_name
     assert all(b > 0 for b in seen["encode"] + seen["upload"] + seen["fetch"])
     assert seen["b_on_device"] == [True, True]
     assert all(u < e for u, e in zip(seen["upload"], seen["encode"]))

@@ -140,3 +140,71 @@ def test_decode_check_matrix_cached():
     flat = np.asarray(i_evals).reshape(plan.n_total, -1)
     coeffs = plan.field.matmul(plan.decode_w, flat[:thr])
     assert np.array_equal(plan.field.matmul(v1, coeffs), flat)
+
+
+def _batched_i_evals(method, s, t, z, n_spare, batch, seed):
+    """A batched replay's device-resident I-evaluations, as the runtime
+    folds them ([n_total, batch * bry, bcy]), and each product's Y."""
+    import jax
+
+    from repro.runtime.scheduler import _batched_compute_closure
+
+    field = Field()
+    rng = np.random.default_rng(seed)
+    sch = C.build_scheme(method, s, t, z)
+    shapes = BlockShapes(k=s * 2, ma=t * 2, mb=t * 3, s=s, t=t)
+    plan = make_plan(sch, shapes, n_spare=n_spare, seed=seed)
+    a = field.random(rng, (batch, shapes.k, shapes.ma))
+    b = field.random(rng, (batch, shapes.k, shapes.mb))
+    a_j, b_j = proto._prep_batched_operands(plan, a, b)
+    fa, fb = proto.share_batched(plan, a_j, b_j, jax.random.PRNGKey(seed))
+    compute = _batched_compute_closure(
+        plan, fa, fb, rng, batch, None, "workers", "all_to_all", "auto"
+    )
+    i_dev = compute(np.arange(plan.n_workers))
+    want = np.stack([field.matmul(a[i].T, b[i]) for i in range(batch)])
+    return plan, i_dev, want
+
+
+@pytest.mark.parametrize("batch", [1, 8])
+@pytest.mark.parametrize("n_spare", [0, 1, 2])
+@pytest.mark.parametrize("case", [("age", 2, 2, 2), ("polydot", 2, 2, 1)])
+def test_device_decode_matches_host_decode(case, n_spare, batch):
+    """The device decode of a responder subset gives the host
+    ``Field.matmul`` decode's first t^2 coefficient rows bit for bit, and
+    Y unfolds from them alone — for the prefix, the slowest tail and
+    subsets that are neither."""
+    from repro.runtime.scheduler import _unfold_batched_y
+
+    plan, i_dev, want = _batched_i_evals(*case, n_spare, batch, seed=5 + n_spare)
+    thr, t2 = plan.decode_threshold, plan.scheme.t ** 2
+    flat = np.asarray(i_dev).reshape(plan.n_total, -1)
+    rng = np.random.default_rng(n_spare)
+    subsets = [np.arange(thr), np.arange(plan.n_total - thr, plan.n_total)] + [
+        np.sort(rng.choice(plan.n_total, size=thr, replace=False)) for _ in range(3)
+    ]
+    for ids in subsets:
+        host = plan.field.matmul(plan.decode_matrix_cached(ids), flat[ids])
+        dev = np.asarray(proto.decode_y_coeffs(plan, i_dev, ids))
+        assert dev.dtype == np.int32 and dev.shape == (t2, flat.shape[1])
+        assert np.array_equal(dev, host[:t2]), ids
+        y = _unfold_batched_y(plan, dev.astype(np.int64), batch)
+        assert np.array_equal(y, want), ids
+        assert np.array_equal(y, _unfold_batched_y(plan, host, batch)), ids
+
+
+def test_device_decode_rows_cached_per_subset():
+    """A subset's device ids and decode rows are uploaded once, in the
+    plan's bounded subset cache beside its host decode matrix."""
+    plan, i_dev, _ = _batched_i_evals("age", 2, 2, 2, 2, 1, seed=9)
+    ids = np.arange(2, 2 + plan.decode_threshold)
+    proto.decode_y_coeffs(plan, i_dev, ids)
+    cache = plan.__dict__["_subset_cache"]
+    key = ("dec_dev", tuple(int(i) for i in ids))
+    ids_dev, rows_dev = cache[key]
+    assert ("dec", key[1]) in cache
+    info = planner.subset_cache_info()
+    proto.decode_y_coeffs(plan, i_dev, ids)
+    assert planner.subset_cache_info()["misses"] == info["misses"]
+    assert cache[key][0] is ids_dev and cache[key][1] is rows_dev
+    assert rows_dev.shape == (plan.scheme.t ** 2, plan.decode_threshold)

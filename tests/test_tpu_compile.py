@@ -87,6 +87,8 @@ def _sites(plan) -> dict:
         # the runtime folds the batch into the payload before the mix
         "phase2_mix": ((nt, nw), (nw, BATCH * bry * bcy)),
         "decode": ((thr, thr), (BATCH, thr, bry * bcy)),
+        # the runtime's decode of Y's t^2 coefficient rows on the device
+        "device_decode": ((plan.scheme.t ** 2, thr), (thr, BATCH * bry * bcy)),
     }
 
 
@@ -109,7 +111,8 @@ def _compile_matmul(sa, sb, sharding, **kw):
 
 
 @pytest.mark.parametrize(
-    "site", ["polyeval_a", "polyeval_b", "worker_multiply", "phase2_mix", "decode"]
+    "site",
+    ["polyeval_a", "polyeval_b", "worker_multiply", "phase2_mix", "decode", "device_decode"],
 )
 def test_matmul_site_compiles_for_v5e(site, plan, one_chip):
     sa, sb = _sites(plan)[site]
@@ -126,6 +129,7 @@ MOE_SITES = {
     "gate_up_multiply": ((8, 21, 64, 3584), (8, 21, 3584, 1024)),
     "down_multiply": ((8, 21, 64, 1024), (8, 21, 1024, 3584)),
     "shared_down_mix": ((21, 17), (17, 1024 * 3584)),
+    "shared_down_decode": ((4, 6), (6, 1024 * 3584)),
 }
 
 
@@ -133,6 +137,19 @@ MOE_SITES = {
 def test_moe_matmul_site_compiles_for_v5e(site, one_chip):
     sa, sb = MOE_SITES[site]
     compiled = _compile_matmul(sa, sb, one_chip, backend="pallas")
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _total_bytes(compiled) < HBM_BYTES
+
+
+def test_device_decode_program_fits_v5e(one_chip, monkeypatch):
+    """The runtime's whole decode program (gather of the responders'
+    rows, then the product) at its largest MoE launch: the shared
+    expert's down product, 21 workers' I-evaluations of 1024 x 3584."""
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    compiled = proto._decode_subset_jit.lower(
+        _spec((21, 1024, 3584), one_chip), _spec((4, 6), one_chip),
+        _spec((6,), one_chip), p=P, backend="auto",
+    ).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert _total_bytes(compiled) < HBM_BYTES
 
