@@ -11,7 +11,8 @@ Layers (bottom-up):
 * ``protocol``       — the 3-phase protocol engine (jit-able, vmapped)
 * ``bw_decode``      — Berlekamp-Welch error-correcting Phase-3 decode
 * ``distributed``    — shard_map execution over a worker mesh axis
-* ``layers``         — secure_matmul / PrivateLinear high-level API
+* ``layers``         — secure_matmul (one, batched, CRT) / PrivateLinear:
+                       synchronous high-level API
 """
 from .bw_decode import (  # noqa: F401
     BWDecodeError,

@@ -2,10 +2,15 @@
 
 ``secure_matmul`` runs one Y = A^T B under CMPC between two logical
 sources, with fixed-point quantisation into GF(p) and centered-lift
-decode.  ``PrivateLinear`` wraps a weight matrix as "source 2" so that
+decode; ``secure_matmul_batched`` and ``secure_matmul_crt`` run a batch
+of products (over one prime, or over several combined by CRT).
+``PrivateLinear`` wraps a weight matrix as "source 2" so that
 activations from "source 1" are multiplied without either worker (or
 the master) learning the operands — the paper's edge-inference setting
 with the transformer stack of this framework as the surrounding model.
+Every call here runs synchronously on an idealized pool; batching
+requests over a simulated edge pool is the serving tier's
+(``repro.serve``).
 
 Overflow discipline: an inner product of length k with operands bounded
 by ``a_max``/``w_max`` needs  k * (a_max*scale_a) * (w_max*scale_w)
@@ -43,189 +48,6 @@ class SecureMatmulResult:
     y: np.ndarray
     trace: protocol.Trace
     plan: CMPCPlan
-
-
-class MatmulHandle:
-    """One deferred Y = A^T B submission against an executor.
-
-    ``submit`` returns immediately with a handle; the numeric result
-    materializes when the owning executor flushes — either explicitly
-    (the batcher decides the group is full) or implicitly on the first
-    ``result()`` of a still-pending handle.  This is the composition
-    point the serving tier batches through: many requests submit, one
-    ``protocol.run_batched`` serves them all.
-    """
-
-    __slots__ = ("_executor", "_value")
-
-    def __init__(self, executor: "InlineExecutor"):
-        self._executor = executor
-        self._value: Optional[SecureMatmulResult] = None
-
-    def done(self) -> bool:
-        return self._value is not None
-
-    def result(self) -> SecureMatmulResult:
-        """The decoded product (flushes the executor when pending)."""
-        if self._value is None:
-            self._executor.flush()
-        assert self._value is not None, "flush did not resolve this handle"
-        return self._value
-
-    def _resolve(self, value: SecureMatmulResult) -> None:
-        self._value = value
-
-
-@dataclasses.dataclass
-class _PendingMatmul:
-    handle: MatmulHandle
-    aq: np.ndarray  # [k, ma], field-encoded
-    bq: np.ndarray  # [k, mb], field-encoded
-    scale: int
-
-
-class InlineExecutor:
-    """Synchronous batching executor for secure matmuls.
-
-    Submissions accumulate per *group* — products with identical
-    ``(method, s, t, z, n_spare, k, ma, mb)`` signatures share one plan
-    and can fold into one batched protocol execution — until
-    :meth:`flush` runs one ``protocol.run_batched`` per group and
-    resolves every handle.  Per-request fixed-point scales survive the
-    fold: encoding happens at submit with the request's own scale, the
-    field-level batch runs scale-oblivious, and each product decodes
-    with its own ``scale**2``.
-
-    This is the data-plane half of continuous batching (shares, device
-    matmuls, decode); the *timing* half — when a batch launches against
-    a simulated pool — lives in ``repro.serve`` which drives the same
-    grouping through ``runtime.PipelineSession``.
-    """
-
-    def __init__(
-        self,
-        field: Optional[Field] = None,
-        backend: str = "auto",
-        seed: int = 0,
-    ):
-        self.field = field or Field()
-        self.backend = backend
-        self.seed = seed
-        self._pending: dict = {}  # group signature -> [_PendingMatmul]
-        self.flushes = 0
-        self.submitted = 0
-
-    def pending(self) -> int:
-        return sum(len(g) for g in self._pending.values())
-
-    def submit(
-        self,
-        a: np.ndarray,
-        b: np.ndarray,
-        method: str = "age",
-        s: int = 2,
-        t: int = 2,
-        z: int = 1,
-        scale: Optional[int] = None,
-        n_spare: int = 0,
-    ) -> MatmulHandle:
-        """Queue one Y = A^T B (a: [k, ma], b: [k, mb]); returns its
-        handle.  ``scale=None`` picks the per-request power-of-two
-        fixed-point scale from this request's operand ranges."""
-        a = np.asarray(a, np.float64)
-        b = np.asarray(b, np.float64)
-        if a.ndim != 2 or b.ndim != 2 or a.shape[0] != b.shape[0]:
-            raise ValueError(
-                f"expected [k, ma] / [k, mb] operands, got {a.shape} {b.shape}"
-            )
-        k, ma = a.shape
-        mb = b.shape[1]
-        if scale is None:
-            scale = choose_scales(
-                k,
-                float(np.abs(a).max() + 1e-9),
-                float(np.abs(b).max() + 1e-9),
-                self.field.p,
-            )
-        key = (method, s, t, z, n_spare, k, ma, mb)
-        handle = MatmulHandle(self)
-        self._pending.setdefault(key, []).append(
-            _PendingMatmul(
-                handle=handle,
-                aq=self.field.encode(a, scale),
-                bq=self.field.encode(b, scale),
-                scale=int(scale),
-            )
-        )
-        self.submitted += 1
-        return handle
-
-    def flush(self) -> int:
-        """Run every pending group through ``protocol.run_batched`` and
-        resolve its handles; returns the number of products served."""
-        pending, self._pending = self._pending, {}
-        served = 0
-        for (method, s, t, z, n_spare, k, ma, mb), group in pending.items():
-            scheme = build_scheme(method, s, t, z)
-            shapes = BlockShapes(k=k, ma=ma, mb=mb, s=s, t=t)
-            plan = get_plan(
-                scheme, shapes, field=self.field, n_spare=n_spare,
-                seed=self.seed,
-            )
-            aq = np.stack([g.aq for g in group])
-            bq = np.stack([g.bq for g in group])
-            yq, trace = protocol.run_batched(
-                plan, aq, bq, seed=self.seed + 1 + self.flushes,
-                backend=self.backend,
-            )
-            self.flushes += 1
-            yq = np.asarray(yq)
-            for i, g in enumerate(group):
-                g.handle._resolve(
-                    SecureMatmulResult(
-                        y=self.field.decode(yq[i], g.scale * g.scale),
-                        trace=trace,
-                        plan=plan,
-                    )
-                )
-            served += len(group)
-        return served
-
-
-def secure_matmul_submit(
-    a: np.ndarray,
-    b: np.ndarray,
-    method: str = "age",
-    s: int = 2,
-    t: int = 2,
-    z: int = 1,
-    field: Optional[Field] = None,
-    scale: Optional[int] = None,
-    n_spare: int = 0,
-    seed: int = 0,
-    backend: str = "auto",
-    executor: Optional[InlineExecutor] = None,
-) -> MatmulHandle:
-    """Async twin of :func:`secure_matmul`: queue the product on an
-    executor and return a :class:`MatmulHandle`.
-
-    With a shared ``executor`` many submissions (from different
-    callers/layers/requests) fold into one batched protocol run at the
-    next flush; without one, a private single-use executor makes
-    ``handle.result()`` equivalent to ``secure_matmul_batched`` at
-    batch 1.  When ``executor`` is given, its field/seed/backend govern
-    and the corresponding arguments here must be left at their
-    defaults.
-    """
-    if executor is None:
-        executor = InlineExecutor(field=field, backend=backend, seed=seed)
-    elif field is not None and field.p != executor.field.p:
-        raise ValueError(
-            f"executor field p={executor.field.p} != requested p={field.p}"
-        )
-    return executor.submit(
-        a, b, method=method, s=s, t=t, z=z, scale=scale, n_spare=n_spare
-    )
 
 
 def secure_matmul(
@@ -377,40 +199,13 @@ def secure_matmul_crt(
     return SecureMatmulResult(y=y, trace=trace, plan=plans[0])
 
 
-class LinearHandle:
-    """Deferred ``PrivateLinear`` application: one part-handle per
-    inner-dim block, summed at :meth:`result`."""
-
-    __slots__ = ("_parts",)
-
-    def __init__(self, parts):
-        self._parts = list(parts)
-
-    def done(self) -> bool:
-        return all(h.done() for h in self._parts)
-
-    def result(self) -> np.ndarray:
-        """[batch, out] activations (flushes pending parts)."""
-        out = self._parts[0].result().y
-        for h in self._parts[1:]:
-            out = out + h.result().y
-        return out
-
-
 class PrivateLinear:
     """y = x @ W via CMPC, W private to the layer owner.
 
     The plan is built once per (k, out, s, t, z) signature and reused
     across calls; the inner dimension may be split into ``blocks``
-    independent protocol instances for extra fixed-point headroom.
-
-    With an ``executor`` (:class:`InlineExecutor`) the layer becomes a
-    submission source: :meth:`submit` queues its per-block products and
-    returns a :class:`LinearHandle`, so many layers/requests sharing
-    one executor fold into one batched protocol run per flush —
-    ``__call__`` then submits + flushes (sync facade over the async
-    path).  Without one, ``__call__`` keeps the historical per-block
-    ``protocol.run`` path unchanged.
+    independent protocol instances (one ``protocol.run`` each) for extra
+    fixed-point headroom.
     """
 
     def __init__(
@@ -423,43 +218,17 @@ class PrivateLinear:
         blocks: int = 1,
         field: Optional[Field] = None,
         seed: int = 0,
-        executor: Optional[InlineExecutor] = None,
     ):
         self.w = np.asarray(w, np.float64)
         self.method, self.s, self.t, self.z = method, s, t, z
         self.blocks = blocks
         self.field = field or Field()
         self.seed = seed
-        self.executor = executor
-        if executor is not None and executor.field.p != self.field.p:
-            raise ValueError(
-                f"executor field p={executor.field.p} != layer p={self.field.p}"
-            )
         # the scheme depends only on ctor args: build it once, not per call
         self._scheme = build_scheme(method, s, t, z)
         k = self.w.shape[0]
         if k % blocks:
             raise ValueError("blocks must divide the inner dimension")
-
-    def submit(self, x: np.ndarray) -> LinearHandle:
-        """Queue x @ W on the layer's executor (requires one); returns
-        a :class:`LinearHandle` resolving to [batch, out]."""
-        if self.executor is None:
-            raise ValueError("PrivateLinear.submit needs an executor")
-        x = np.asarray(x, np.float64)
-        _, k = x.shape
-        kblk = k // self.blocks
-        parts = []
-        for bi in range(self.blocks):
-            sl = slice(bi * kblk, (bi + 1) * kblk)
-            parts.append(
-                self.executor.submit(
-                    x[:, sl].T,  # [kblk, batch] == "A"
-                    self.w[sl],  # [kblk, out]  == "B"
-                    method=self.method, s=self.s, t=self.t, z=self.z,
-                )
-            )
-        return LinearHandle(parts)
 
     def _plan(self, batch: int, kblk: int) -> CMPCPlan:
         # Delegates to the process-wide plan cache (planner.get_plan):
@@ -470,10 +239,6 @@ class PrivateLinear:
 
     def __call__(self, x: np.ndarray) -> np.ndarray:
         """x: [batch, k] activations (source 1).  Returns [batch, out]."""
-        if self.executor is not None:
-            handle = self.submit(x)
-            self.executor.flush()
-            return handle.result()
         x = np.asarray(x, np.float64)
         batch, k = x.shape
         kblk = k // self.blocks

@@ -18,29 +18,27 @@ setting: heterogeneous, flaky edge workers.
                    is possible, or Byzantine error *correction* via
                    ``decode_mode="correct"`` — one Berlekamp-Welch decode
                    over the fastest ``thr + 2e`` responders that also
-                   names the corrupt workers);
-                   ``run_batch_over_pool`` replays a whole
-                   batch of products through one trace — event loop and
-                   decode-subset search amortized across the batch — and
-                   with a mesh drives the real ``shard_map`` Phase-2
-                   exchange from the scheduler's fastest subset,
+                   names the corrupt workers),
 * ``metrics``   — per-run timeline, communication (bytes-level
                    ``Trace`` view), effective worker counts and
                    decode-subset statistics, plus aggregation across
                    runs,
-* ``pipeline``  — ``run_pipeline_over_pool`` keeps K batched replays
-                   in flight at once with overlapping traces: master
-                   links and worker compute are serial resources, so
-                   replay k+1's Phase-1 transfers overlap replay k's
-                   Phase-2 compute; aggregate ``PipelineMetrics``
+* ``pipeline``  — the replay: ``PipelineSession`` shares a batch on
+                   the device engine, folds it into each worker's
+                   payload (event loop and decode-subset search
+                   amortized across the batch; with a mesh the Phase-2
+                   exchange is the real ``shard_map`` collective) and
+                   *appends* replays one at a time against the live
+                   occupancy: master links and worker compute are
+                   serial resources, so replay k+1's Phase-1 transfers
+                   overlap replay k's Phase-2 compute (optionally
+                   floored by a request-arrival ``not_before``), which
+                   is what lets the serving tier (``repro.serve``)
+                   admit requests into an in-flight pipeline.
+                   ``run_pipeline_over_pool`` appends K replays;
+                   ``run_batch_over_pool`` / ``run_over_pool`` are
+                   one-append sessions.  Aggregate ``PipelineMetrics``
                    report makespan, occupancy, and Phase-1 overlap.
-                   The stateful core is ``PipelineSession``: replays
-                   are *appended* one at a time against the live
-                   occupancy (optionally floored by a request-arrival
-                   ``not_before``), which is what lets the serving
-                   tier (``repro.serve``) admit requests into an
-                   in-flight pipeline instead of waiting for batch
-                   boundaries.
 
 Traces can be link-resolved: ``NetworkModel`` implementations
 (``UniformLinks`` / ``AsymmetricLinks`` / ``ClusteredEdge``) sample a
@@ -75,12 +73,8 @@ from .pool import (  # noqa: F401
 )
 from .scheduler import (  # noqa: F401
     DEFAULT_SUBSET_TRIES,
-    BatchEdgeRun,
     DecodeFailure,
-    EdgeRun,
     HybridState,
-    run_batch_over_pool,
-    run_over_pool,
 )
 from .metrics import (  # noqa: F401
     ObservedRun,
@@ -94,9 +88,13 @@ from .metrics import (  # noqa: F401
     summarize,
 )
 from .pipeline import (  # noqa: F401
+    BatchEdgeRun,
+    EdgeRun,
     PipelineReplay,
     PipelineRun,
     PipelineSession,
+    run_batch_over_pool,
+    run_over_pool,
     run_pipeline_over_pool,
 )
 from .autoplan import (  # noqa: F401

@@ -57,7 +57,7 @@ from .metrics import (
     order_stat_mean,
 )
 from .pool import ElasticPool, WorkerTrace
-from .scheduler import BatchEdgeRun, run_batch_over_pool
+from .pipeline import BatchEdgeRun, run_batch_over_pool
 
 
 @dataclasses.dataclass(frozen=True)

@@ -1,12 +1,16 @@
-"""Pipelined batched replays: K protocol executions in flight at once.
+"""The replay: batched protocol executions over the pool, K in flight.
 
-``run_batch_over_pool`` amortizes the event loop across a *batch* of
-products, but successive batches still run back-to-back: replay k+1's
-Phase-1 upload waits for replay k's decode, even though the master's
-links and the workers sit idle for most of that span.  This module
-overlaps them — the ROADMAP's "pipelining many batched replays with
-overlapping traces" item, and its Phase-1/Phase-2 overlap rule is the
-"overlapping Phase-1 transfers with Phase-2 compute" item.
+This module is the one place a replay is built: it shares the operands
+on the batched device engine, folds the batch into each worker's
+payload (``_batched_compute_closure``), resolves the corruption knobs
+and drives the scheduler's event loop.  One batched replay amortizes
+the event loop across a *batch* of products; back-to-back replays
+would still leave replay k+1's Phase-1 upload waiting for replay k's
+decode, even though the master's links and the workers sit idle for
+most of that span.  The session overlaps them — the ROADMAP's
+"pipelining many batched replays with overlapping traces" item, and its
+Phase-1/Phase-2 overlap rule is the "overlapping Phase-1 transfers with
+Phase-2 compute" item.
 
 Pipeline timing model (two serial resources, everything else overlaps):
 
@@ -39,7 +43,7 @@ accounting lands in :class:`~repro.runtime.metrics.PipelineMetrics`
 (makespan, per-replay spans, pipeline occupancy, Phase-1 overlap, and
 the summed communication ``Trace``).
 
-Two entry points share one implementation:
+Every entry point is a :class:`PipelineSession`:
 
 * :class:`PipelineSession` — the stateful core: replays are *appended*
   one at a time against the live link/compute occupancy, so a caller
@@ -50,18 +54,24 @@ Two entry points share one implementation:
   construction (per-append planner decisions).
 * :func:`run_pipeline_over_pool` — the fixed-K convenience wrapper:
   prepares a ``[K, batch, k, m]`` operand stack and appends each slice
-  back-to-back.  Replays byte-identically to the pre-session
-  implementation (same rng streams, same timestamps).
+  back-to-back.
+* :func:`run_batch_over_pool` / :func:`run_over_pool` — one batch (one
+  product) through one trace: a one-append session.  With nothing
+  before it, the append's timeline is the standalone one: shares
+  arrive at ``share_delay``, H(alpha_n) finishes ``compute_scale *
+  compute_delay`` later, and a dropout never enters the event heap.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Optional, Sequence
+from typing import Callable, List, Optional, Sequence
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..core import protocol as proto
+from ..core.distributed import run_phase2_sharded
 from ..core.planner import CMPCPlan
 from ..obs.metrics import REGISTRY
 from ..obs.tracer import TRACER
@@ -70,7 +80,6 @@ from .pool import WorkerTrace
 from .scheduler import (
     DEFAULT_SUBSET_TRIES,
     HybridState,
-    _batched_compute_closure,
     _build_metrics,
     _check_pool,
     _replay_events,
@@ -78,8 +87,93 @@ from .scheduler import (
     _resolve_error_budget,
     _resolve_hybrid,
     _resolve_verify_extras,
-    _unfold_batched_y,
 )
+
+
+@dataclasses.dataclass
+class EdgeRun:
+    """Result of one execution over the pool."""
+
+    y: np.ndarray
+    metrics: RunMetrics
+
+
+@dataclasses.dataclass
+class BatchEdgeRun:
+    """Result of one batched execution over the pool.
+
+    One event-loop replay served every product: ``per_product`` metrics
+    share the timeline and subsets, differing only in the (per-product)
+    communication trace; ``metrics`` carries the whole-batch trace.
+    The subset id arrays (``phase2_ids``, ``responder_ids``, ...) are
+    shared views across entries and the aggregate — treat them as
+    read-only.
+    """
+
+    y: np.ndarray  # [batch, ma, mb]
+    metrics: RunMetrics  # aggregate (batch-level comm accounting)
+    per_product: List[RunMetrics]
+
+
+def _batched_compute_closure(
+    plan: CMPCPlan,
+    fa: jnp.ndarray,
+    fb: jnp.ndarray,
+    rng: np.random.Generator,
+    batch: int,
+    mesh,
+    axis: str,
+    mode: str,
+    backend: str,
+    replay: int = 0,
+) -> Callable[[np.ndarray], np.ndarray]:
+    """``compute_i_all`` for a batched replay.
+
+    Folds the whole batch into each worker's payload so one Phase-2
+    pass serves every product; with ``mesh`` the exchange is the real
+    ``shard_map`` collective driven by the scheduler's fastest subset.
+    A ``protocol.phase2`` wall span (``replay`` = session index) covers
+    each call, so every Phase-2 device program is launched under it.
+    """
+    bry, bcy = plan.shapes.blk_y
+
+    def compute_i_all(phase2_ids: np.ndarray) -> np.ndarray:
+        with TRACER.span("protocol.phase2", replay=replay):
+            if mesh is not None:
+                # Faithful distributed exchange: per-worker blinding draws,
+                # whole batch on one collective, sender subset = the
+                # scheduler's fastest n_workers.
+                noise = plan.field.random(
+                    rng, (batch, plan.n_workers, plan.scheme.z, bry, bcy)
+                )
+                i_b = run_phase2_sharded(
+                    plan, fa, fb, noise, mesh,
+                    axis=axis, mode=mode, matmul_backend=backend,
+                    worker_ids=phase2_ids,
+                )  # [batch, n_total, bry, bcy]
+                return np.moveaxis(np.asarray(i_b), 1, 0).reshape(
+                    plan.n_total, batch * bry, bcy
+                )
+            # Dense simulation: fold the batch into the block rows so the
+            # existing degree-reduction matmul serves every product at once.
+            h = proto.worker_multiply(plan, fa, fb)  # [batch, n_total, bry, bcy]
+            h_w = jnp.moveaxis(h, 0, 1).reshape(plan.n_total, batch * bry, bcy)
+            return proto.degree_reduce(plan, h_w, rng, worker_ids=phase2_ids)
+
+    return compute_i_all
+
+
+def _unfold_batched_y(plan: CMPCPlan, coeffs: np.ndarray, batch: int) -> np.ndarray:
+    """Per-product assembly: the interpolated coefficients carry the
+    batch in their payload; unfold and lay out every Y at once (the
+    batched mirror of ``assemble_y``)."""
+    t = plan.scheme.t
+    sh = plan.shapes
+    bry, bcy = sh.blk_y
+    blocks = coeffs.reshape(-1, batch, bry, bcy)[: t * t].reshape(
+        t, t, batch, bry, bcy
+    )  # [l, i, b, ., .]
+    return blocks.transpose(2, 1, 3, 0, 4).reshape(batch, sh.ma, sh.mb)
 
 
 @dataclasses.dataclass
@@ -289,9 +383,10 @@ class PipelineSession:
         ``not_before`` floors the upload start on every master link —
         the serving engine passes the launch time its admission loop
         chose (>= the admitted requests' arrivals).  Raises
-        :class:`~repro.runtime.scheduler.DecodeFailure` exactly like
-        the standalone entry points; a failed append leaves the
-        occupancy state untouched (the replay never ran).
+        :class:`~repro.runtime.scheduler.DecodeFailure` when the pool
+        cannot complete the protocol (too few live workers, or no
+        acceptable decode); a failed append leaves the occupancy state
+        untouched (the replay never ran).
 
         With tracing on, a ``runtime.replay`` wall span covers the
         whole append: shares, exchange, the device-to-host copy of the
@@ -366,7 +461,7 @@ class PipelineSession:
         # upload_free/worker_free commit only after the replay succeeds
         # (a DecodeFailure must not half-advance the occupancy).
 
-        # -- numeric path: same batched engine as run_batch_over_pool --
+        # -- numeric path: the batched device engine --
         with TRACER.span("runtime.upload", replay=k) as sp:
             a_j, b_j = proto._prep_batched_operands(plan_k, a, b)
             sp.set(bytes=sum(
@@ -401,8 +496,8 @@ class PipelineSession:
             extras_k,
             rng,
             self._master_decode_cost,
-            share_arrival=arrive,
-            compute_finish=finish,
+            share_at=arrive,
+            finish_at=finish,
             decode_mode=mode_k,
             error_budget=budget_k,
             max_subset_tries=self._max_subset_tries,
@@ -524,9 +619,9 @@ def run_pipeline_over_pool(
     draw).  Replay k+1's Phase-1 upload to each worker starts as soon
     as that master link is free, so transfers overlap earlier replays'
     Phase-2 compute; each replay then fixes its own Phase-2 subset and
-    decode subset through the shared event loop.  Per-replay decode
-    failures raise :class:`DecodeFailure` exactly like the standalone
-    entry points.
+    decode subset through the shared event loop.  A replay's decode
+    failure raises :class:`DecodeFailure` as :meth:`PipelineSession.append`
+    does.
 
     With ``planner`` (an :class:`~repro.runtime.autoplan.AutoPlanner`)
     the construction is chosen *per replay* at the pipeline's replay
@@ -599,3 +694,138 @@ def run_pipeline_over_pool(
     for k, trace in enumerate(traces):
         session.append(a[k], b[k], trace)
     return session.result()
+
+
+def _per_product(m: RunMetrics) -> RunMetrics:
+    """One product's share of a batched replay's metrics: the replay's
+    timeline and subsets, and the communication of one product (every
+    count is linear in the batch)."""
+    t, n = m.trace, m.batch
+    return dataclasses.replace(m, batch=1, trace=proto.Trace(
+        phase1_source_to_worker=t.phase1_source_to_worker // n,
+        phase2_worker_to_worker=t.phase2_worker_to_worker // n,
+        phase3_worker_to_master=t.phase3_worker_to_master // n,
+        elem_bytes=t.elem_bytes,
+    ))
+
+
+def run_batch_over_pool(
+    plan: CMPCPlan,
+    a: np.ndarray,
+    b: np.ndarray,
+    trace: WorkerTrace,
+    seed: int = 0,
+    verify_extras="auto",
+    master_decode_cost: float = 0.0,
+    mesh=None,
+    axis: str = "workers",
+    mode: str = "all_to_all",
+    backend: str = "auto",
+    compute_scale: float = 1.0,
+    decode_mode: str = "detect",
+    error_budget="auto",
+    max_subset_tries: int = DEFAULT_SUBSET_TRIES,
+    obs_attrs: Optional[dict] = None,
+    hybrid_state: Optional[HybridState] = None,
+) -> BatchEdgeRun:
+    """Replay a whole batch of products through ONE worker trace: a
+    one-append :class:`PipelineSession`.
+
+    a: [batch, k, ma], b: [batch, k, mb] (2D operands promote to batch
+    1).  The event loop, Phase-2 fastest-subset barrier, and the
+    decode-subset search run once for the whole batch: products fold
+    into each worker's payload, which is sound because the timeline
+    depends only on the trace, and a corrupt/crashed/dropped worker is
+    faulty for every product it touches.
+
+    With ``mesh`` the Phase-2 exchange is the real ``shard_map``
+    collective (``core.distributed.run_phase2_sharded``, ``mode`` one of
+    ``all_to_all`` / ``psum`` / ``psum_scatter``) driven by the
+    scheduler's fastest-subset ``worker_ids``.  Without it, Phase 2 is
+    the dense single-host simulation (``degree_reduce``).
+
+    ``compute_scale`` multiplies every worker's compute delay — the
+    hook for heterogeneous-work comparisons, where one trace's
+    ``compute_delay`` is time per unit work and each construction's
+    per-worker work (Corollary 10; ``CostPrediction.compute_factor``)
+    sets the scale.
+
+    ``decode_mode`` selects corruption handling (``runtime.scheduler``'s
+    docstring): ``"detect"`` confirm-and-retry (the default;
+    ``verify_extras`` confirmations, subset search bounded by
+    ``max_subset_tries``), ``"correct"`` one Berlekamp-Welch decode over
+    the fastest ``thr + 2 * error_budget`` responders (each corrupt
+    worker's whole folded payload is corrected at once), ``"auto"``
+    correct iff the resolved error budget is positive, ``"hybrid"``
+    detect until the first rejection recorded in ``hybrid_state`` then
+    correct.  ``error_budget="auto"`` resolves from the trace's
+    configured fault model.
+
+    Randomness: the session's replay 0 draws from
+    ``default_rng([seed, 0])`` and ``fold_in(PRNGKey(seed), 0)``.
+
+    Returns :class:`BatchEdgeRun`; raises :class:`DecodeFailure` when
+    the surviving pool cannot serve Phase 2 (fewer than ``n_workers``
+    live workers) or the master never accumulates an acceptable
+    responder subset.
+    """
+    session = PipelineSession(
+        plan,
+        seed=seed,
+        verify_extras=verify_extras,
+        master_decode_cost=master_decode_cost,
+        mesh=mesh,
+        axis=axis,
+        mode=mode,
+        backend=backend,
+        compute_scale=compute_scale,
+        decode_mode=decode_mode,
+        error_budget=error_budget,
+        max_subset_tries=max_subset_tries,
+        hybrid_state=hybrid_state,
+    )
+    replay = session.append(a, b, trace, obs_attrs=obs_attrs)
+    # one replay served every product, so the per-product metrics are
+    # identical by construction: build once, then give each entry its
+    # own object (the subset id arrays stay shared read-only views)
+    first = _per_product(replay.metrics)
+    per_product = [first] + [
+        dataclasses.replace(first) for _ in range(replay.batch - 1)
+    ]
+    return BatchEdgeRun(y=replay.y, metrics=replay.metrics, per_product=per_product)
+
+
+def run_over_pool(
+    plan: CMPCPlan,
+    a: np.ndarray,
+    b: np.ndarray,
+    trace: WorkerTrace,
+    seed: int = 0,
+    verify_extras="auto",
+    master_decode_cost: float = 0.0,
+    compute_scale: float = 1.0,
+    decode_mode: str = "detect",
+    error_budget="auto",
+    max_subset_tries: int = DEFAULT_SUBSET_TRIES,
+    obs_attrs: Optional[dict] = None,
+    hybrid_state: Optional[HybridState] = None,
+) -> EdgeRun:
+    """Execute Y = A^T B over the simulated pool described by ``trace``:
+    the batch-1 case of :func:`run_batch_over_pool` (same knobs, same
+    :class:`DecodeFailure`).
+
+    Returns the decoded product and the run's :class:`RunMetrics`.
+    """
+    run = run_batch_over_pool(
+        plan, a, b, trace,
+        seed=seed,
+        verify_extras=verify_extras,
+        master_decode_cost=master_decode_cost,
+        compute_scale=compute_scale,
+        decode_mode=decode_mode,
+        error_budget=error_budget,
+        max_subset_tries=max_subset_tries,
+        obs_attrs=obs_attrs,
+        hybrid_state=hybrid_state,
+    )
+    return EdgeRun(y=run.y[0], metrics=run.per_product[0])

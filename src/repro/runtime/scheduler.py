@@ -56,42 +56,28 @@ sampled ``trace.corrupt`` flags, which are ground truth the master
 cannot see.  A hand-built corrupt trace with no fault model therefore
 gets NO automatic protection — exactly the honest semantics.
 
-Two replay entry points share ONE event loop (``_replay_events``):
-
-* ``run_over_pool``        — per-product reference (numpy-rng share
-                              path, dense Phase-2 simulation),
-* ``run_batch_over_pool``  — a whole batch of products through one
-                              trace: shares come from the jitted
-                              batched engine, the batch folds into the
-                              per-worker payload so the event loop,
-                              Phase-2 subset selection, and the
-                              decode-subset search are paid ONCE, and
-                              with ``mesh`` the exchange is the real
-                              ``shard_map`` collective of
-                              ``core.distributed`` driven by the
-                              scheduler's fastest-subset ``worker_ids``.
-
-The numeric path stays on the device-resident protocol ops
-(``share_a/b``, ``worker_multiply``, ``degree_reduce``,
-``share_batched``, ``run_phase2_sharded``); the event loop only decides
-subsets and timestamps — which is what makes the batch fold sound: the
-timeline depends on the trace alone, and a corrupt worker is corrupt
-for every product it serves.
+This module holds the event loop (``_replay_events``), the decodes it
+runs (``_device_decode``, ``_try_decode``, Berlekamp-Welch), the
+metrics builder and the knob resolvers.  It builds no replay itself:
+``runtime.pipeline.PipelineSession`` shares the operands, supplies the
+Phase-2 closure and calls the loop, and the standalone entry points
+(``run_over_pool``, ``run_batch_over_pool``) are one-append sessions.
+The event loop only decides subsets and timestamps — which is what
+makes the batch fold sound: the timeline depends on the trace alone,
+and a corrupt worker is corrupt for every product it serves.
 """
 from __future__ import annotations
 
 import dataclasses
 import heapq
 import itertools
-from typing import Callable, List, Optional, Tuple
+from typing import Callable, Optional, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from ..core import protocol as proto
 from ..core.bw_decode import BWDecodeError, bw_decode_evals, bw_system_size
-from ..core.distributed import run_phase2_sharded
 from ..core.planner import CMPCPlan
 from ..obs.metrics import REGISTRY
 from ..obs.tracer import TRACER
@@ -103,31 +89,6 @@ _EMPTY_IDS = np.array([], np.int64)
 
 class DecodeFailure(RuntimeError):
     """The pool could not complete the protocol (too many faults)."""
-
-
-@dataclasses.dataclass
-class EdgeRun:
-    """Result of one execution over the pool."""
-
-    y: np.ndarray
-    metrics: RunMetrics
-
-
-@dataclasses.dataclass
-class BatchEdgeRun:
-    """Result of one batched execution over the pool.
-
-    One event-loop replay served every product: ``per_product`` metrics
-    share the timeline and subsets, differing only in the (per-product)
-    communication trace; ``metrics`` carries the whole-batch trace.
-    The subset id arrays (``phase2_ids``, ``responder_ids``, ...) are
-    shared views across entries and the aggregate — treat them as
-    read-only.
-    """
-
-    y: np.ndarray  # [batch, ma, mb]
-    metrics: RunMetrics  # aggregate (batch-level comm accounting)
-    per_product: List[RunMetrics]
 
 
 # Default bound on per-event decode-subset search when hunting for a
@@ -254,9 +215,8 @@ def _replay_events(
     verify_extras: int,
     rng: np.random.Generator,
     master_decode_cost: float,
-    share_arrival: Optional[np.ndarray] = None,
-    compute_finish: Optional[np.ndarray] = None,
-    compute_scale: float = 1.0,
+    share_at: np.ndarray,
+    finish_at: np.ndarray,
     decode_mode: str = "detect",
     error_budget: int = 0,
     max_subset_tries: int = DEFAULT_SUBSET_TRIES,
@@ -270,20 +230,11 @@ def _replay_events(
     corruption is injected here so every caller gets identical fault
     semantics.
 
-    ``share_arrival`` / ``compute_finish`` override the trace-derived
-    Phase-1 arrival and H(alpha_n) completion times with absolute
-    timestamps — the hook the pipelined runtime uses to account for
-    master-uplink serialization and per-worker compute occupancy
-    across overlapping replays.  Defaults reproduce the standalone
-    semantics: arrival at ``share_delay``, completion one
-    ``compute_delay`` later.
-
-    ``compute_scale`` multiplies every worker's compute delay — the
-    hook for heterogeneous-work comparisons, where one trace's
-    ``compute_delay`` is time per unit work and each construction's
-    per-worker work (Corollary 10; ``CostPrediction.compute_factor``)
-    sets the scale.  The default 1.0 keeps replays byte-identical to
-    the legacy semantics.
+    ``share_at`` / ``finish_at`` are each worker's absolute
+    Phase-1 arrival and H(alpha_n) completion times: the session derives
+    them from the trace, the master-uplink serialization and per-worker
+    compute occupancy across overlapping replays, and the compute scale.
+    A dropout's entry is ignored: it never enters the event heap.
 
     With a link-resolved trace (``trace.link_delay`` set), a receiver's
     exchange completes at the max over its *incoming* links from the
@@ -308,13 +259,7 @@ def _replay_events(
     tracing = TRACER.enabled
     replay = (obs_attrs or {}).get("replay")  # session index on the wall spans
     p = plan.field.p
-    share_at = trace.share_delay if share_arrival is None else share_arrival
     phase1_last = float(share_at[alive].max())
-    finish_at = (
-        share_at + compute_scale * trace.compute_delay
-        if compute_finish is None
-        else compute_finish
-    )
 
     # Heap entries: (time, seq, kind, worker).
     events: list = []
@@ -661,214 +606,6 @@ def _resolve_hybrid(
     if budget <= 0:
         return "detect", error_budget, state
     return "correct", budget, state
-
-
-def run_over_pool(
-    plan: CMPCPlan,
-    a: np.ndarray,
-    b: np.ndarray,
-    trace: WorkerTrace,
-    seed: int = 0,
-    verify_extras="auto",
-    master_decode_cost: float = 0.0,
-    compute_scale: float = 1.0,
-    decode_mode: str = "detect",
-    error_budget="auto",
-    max_subset_tries: int = DEFAULT_SUBSET_TRIES,
-    obs_attrs: Optional[dict] = None,
-    hybrid_state: Optional[HybridState] = None,
-) -> EdgeRun:
-    """Execute Y = A^T B over the simulated pool described by ``trace``.
-
-    ``decode_mode`` selects corruption handling (module docstring):
-    ``"detect"`` confirm-and-retry (the default; ``verify_extras``
-    confirmations, subset search bounded by ``max_subset_tries``),
-    ``"correct"`` one Berlekamp-Welch decode over the fastest
-    ``thr + 2 * error_budget`` responders, ``"auto"`` correct iff the
-    resolved error budget is positive, ``"hybrid"`` detect until the
-    first rejection recorded in ``hybrid_state`` then correct.
-    ``error_budget="auto"`` resolves from the trace's configured fault
-    model.
-
-    Returns the decoded product and the run's :class:`RunMetrics`.
-    Raises :class:`DecodeFailure` when the surviving pool cannot serve
-    Phase 2 (fewer than ``n_workers`` live workers) or the master never
-    accumulates an acceptable responder subset.
-    """
-    alive = _check_pool(plan, trace)
-    verify_extras = _resolve_verify_extras(verify_extras, trace)
-    error_budget = _resolve_error_budget(error_budget, trace, plan)
-    decode_mode, error_budget, hybrid_state = _resolve_hybrid(
-        decode_mode, hybrid_state, error_budget, plan
-    )
-    decode_mode = _resolve_decode_mode(decode_mode, error_budget)
-    rng = np.random.default_rng(seed)
-
-    # Data plane, Phase 1: sources evaluate and ship shares.
-    fa = proto.share_a(plan, a, rng)
-    fb = proto.share_b(plan, b, rng)
-    h = proto.worker_multiply(plan, fa, fb)
-
-    def compute_i_all(phase2_ids: np.ndarray) -> np.ndarray:
-        return proto.degree_reduce(plan, h, rng, worker_ids=phase2_ids)
-
-    res = _replay_events(
-        plan, trace, alive, compute_i_all, verify_extras, rng,
-        master_decode_cost, compute_scale=compute_scale,
-        decode_mode=decode_mode, error_budget=error_budget,
-        max_subset_tries=max_subset_tries, obs_attrs=obs_attrs,
-    )
-    y = proto.assemble_y(plan, res.coeffs)
-    metrics = _build_metrics(plan, trace, alive, res)
-    if hybrid_state is not None:
-        hybrid_state.note(metrics)
-    return EdgeRun(y=y, metrics=metrics)
-
-
-def _batched_compute_closure(
-    plan: CMPCPlan,
-    fa: jnp.ndarray,
-    fb: jnp.ndarray,
-    rng: np.random.Generator,
-    batch: int,
-    mesh,
-    axis: str,
-    mode: str,
-    backend: str,
-    replay: int = 0,
-) -> Callable[[np.ndarray], np.ndarray]:
-    """``compute_i_all`` for a batched replay (shared with the pipeline).
-
-    Folds the whole batch into each worker's payload so one Phase-2
-    pass serves every product; with ``mesh`` the exchange is the real
-    ``shard_map`` collective driven by the scheduler's fastest subset.
-    A ``protocol.phase2`` wall span (``replay`` = session index) covers
-    each call, so every Phase-2 device program is launched under it.
-    """
-    bry, bcy = plan.shapes.blk_y
-
-    def compute_i_all(phase2_ids: np.ndarray) -> np.ndarray:
-        with TRACER.span("protocol.phase2", replay=replay):
-            if mesh is not None:
-                # Faithful distributed exchange: per-worker blinding draws,
-                # whole batch on one collective, sender subset = the
-                # scheduler's fastest n_workers.
-                noise = plan.field.random(
-                    rng, (batch, plan.n_workers, plan.scheme.z, bry, bcy)
-                )
-                i_b = run_phase2_sharded(
-                    plan, fa, fb, noise, mesh,
-                    axis=axis, mode=mode, matmul_backend=backend,
-                    worker_ids=phase2_ids,
-                )  # [batch, n_total, bry, bcy]
-                return np.moveaxis(np.asarray(i_b), 1, 0).reshape(
-                    plan.n_total, batch * bry, bcy
-                )
-            # Dense simulation: fold the batch into the block rows so the
-            # existing degree-reduction matmul serves every product at once.
-            h = proto.worker_multiply(plan, fa, fb)  # [batch, n_total, bry, bcy]
-            h_w = jnp.moveaxis(h, 0, 1).reshape(plan.n_total, batch * bry, bcy)
-            return proto.degree_reduce(plan, h_w, rng, worker_ids=phase2_ids)
-
-    return compute_i_all
-
-
-def _unfold_batched_y(plan: CMPCPlan, coeffs: np.ndarray, batch: int) -> np.ndarray:
-    """Per-product assembly: the interpolated coefficients carry the
-    batch in their payload; unfold and lay out every Y at once (the
-    batched mirror of ``assemble_y``)."""
-    t = plan.scheme.t
-    sh = plan.shapes
-    bry, bcy = sh.blk_y
-    blocks = coeffs.reshape(-1, batch, bry, bcy)[: t * t].reshape(
-        t, t, batch, bry, bcy
-    )  # [l, i, b, ., .]
-    return blocks.transpose(2, 1, 3, 0, 4).reshape(batch, sh.ma, sh.mb)
-
-
-def run_batch_over_pool(
-    plan: CMPCPlan,
-    a: np.ndarray,
-    b: np.ndarray,
-    trace: WorkerTrace,
-    seed: int = 0,
-    verify_extras="auto",
-    master_decode_cost: float = 0.0,
-    mesh=None,
-    axis: str = "workers",
-    mode: str = "all_to_all",
-    backend: str = "auto",
-    compute_scale: float = 1.0,
-    decode_mode: str = "detect",
-    error_budget="auto",
-    max_subset_tries: int = DEFAULT_SUBSET_TRIES,
-    obs_attrs: Optional[dict] = None,
-    hybrid_state: Optional[HybridState] = None,
-) -> BatchEdgeRun:
-    """Replay a whole batch of products through ONE worker trace.
-
-    a: [batch, k, ma], b: [batch, k, mb] (2D operands promote to batch
-    1).  The event loop, Phase-2 fastest-subset barrier, and the
-    decode-subset search run once for the whole batch: products fold
-    into each worker's payload, which is sound because the timeline
-    depends only on the trace, and a corrupt/crashed/dropped worker is
-    faulty for every product it touches.  Shares and decode run on the
-    batched device engine (``share_batched`` / jitted decode path).
-
-    With ``mesh`` the Phase-2 exchange is the real ``shard_map``
-    collective (``core.distributed.run_phase2_sharded``, ``mode`` one of
-    ``all_to_all`` / ``psum`` / ``psum_scatter``) driven by the
-    scheduler's fastest-subset ``worker_ids`` — the edge runtime and the
-    distributed data plane composed end to end.  Without it, Phase 2 is
-    the dense single-host simulation (``degree_reduce``).
-
-    ``decode_mode`` / ``error_budget`` / ``max_subset_tries`` select the
-    corruption-handling strategy exactly as in ``run_over_pool``; a
-    Berlekamp-Welch decode (``"correct"``) corrects each corrupt
-    worker's whole folded payload at once, so the whole batch rides one
-    error-correcting decode.
-
-    Returns :class:`BatchEdgeRun`; raises :class:`DecodeFailure` exactly
-    like ``run_over_pool``.
-    """
-    alive = _check_pool(plan, trace)
-    verify_extras = _resolve_verify_extras(verify_extras, trace)
-    error_budget = _resolve_error_budget(error_budget, trace, plan)
-    decode_mode, error_budget, hybrid_state = _resolve_hybrid(
-        decode_mode, hybrid_state, error_budget, plan
-    )
-    decode_mode = _resolve_decode_mode(decode_mode, error_budget)
-    rng = np.random.default_rng(seed)
-
-    a_j, b_j = proto._prep_batched_operands(plan, a, b)
-    batch = int(a_j.shape[0])
-    fa, fb = proto.share_batched(
-        plan, a_j, b_j, jax.random.PRNGKey(seed), backend=backend
-    )
-    compute_i_all = _batched_compute_closure(
-        plan, fa, fb, rng, batch, mesh, axis, mode, backend
-    )
-
-    res = _replay_events(
-        plan, trace, alive, compute_i_all, verify_extras, rng,
-        master_decode_cost, compute_scale=compute_scale,
-        decode_mode=decode_mode, error_budget=error_budget,
-        max_subset_tries=max_subset_tries,
-        obs_attrs={**(obs_attrs or {}), "batch": batch},
-    )
-    y = _unfold_batched_y(plan, res.coeffs, batch)
-
-    aggregate = _build_metrics(plan, trace, alive, res, batch=batch)
-    if hybrid_state is not None:
-        hybrid_state.note(aggregate)
-    # one replay served every product, so the per-product metrics are
-    # identical by construction: build once, then give each entry its
-    # own object (the subset id arrays stay shared read-only views)
-    first = _build_metrics(plan, trace, alive, res, batch=1)
-    per_product = [first] + [
-        dataclasses.replace(first) for _ in range(batch - 1)
-    ]
-    return BatchEdgeRun(y=y, metrics=aggregate, per_product=per_product)
 
 
 def _candidate_subsets(

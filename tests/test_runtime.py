@@ -369,21 +369,14 @@ def _force_host_decode(monkeypatch):
     """Hand the event loop host copies of the I-evaluations, as the mesh
     path does: every replay then decodes on the host."""
     import repro.runtime.pipeline as pipeline
-    import repro.runtime.scheduler as scheduler
-    from repro.core import protocol as proto
 
-    real_closure = scheduler._batched_compute_closure
-    real_reduce = proto.degree_reduce
+    real_closure = pipeline._batched_compute_closure
 
     def closure(*args, **kw):
         compute = real_closure(*args, **kw)
         return lambda ids: np.asarray(compute(ids))
 
-    monkeypatch.setattr(scheduler, "_batched_compute_closure", closure)
     monkeypatch.setattr(pipeline, "_batched_compute_closure", closure)
-    monkeypatch.setattr(
-        proto, "degree_reduce", lambda *a, **kw: np.asarray(real_reduce(*a, **kw))
-    )
 
 
 def _replays(entry, plan, a1, b1, traces):

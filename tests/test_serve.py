@@ -1,5 +1,5 @@
 """Serving tier: request lifecycle, continuous batching, SLO/admission
-semantics, and the async submission API under it.
+semantics, and the served path under every fault kind.
 
 The engine is a pure function of (requests, traces, seed): every test
 below runs on deterministic traces and asserts exact censuses — decode
@@ -16,19 +16,14 @@ import pytest
 
 from repro.core.constructions import PlanConfig
 from repro.core.gf import Field
-from repro.core.layers import (
-    InlineExecutor,
-    PrivateLinear,
-    choose_scales,
-    secure_matmul,
-    secure_matmul_submit,
-)
+from repro.core.layers import choose_scales
 from repro.core.planner import BlockShapes, get_plan_for
 from repro.obs import TRACER
 from repro.runtime import (
     Deterministic,
     PipelineSession,
     ShiftedExponential,
+    run_batch_over_pool,
     run_pipeline_over_pool,
     sample_trace,
 )
@@ -312,6 +307,70 @@ def test_engine_hybrid_escalates_and_corrects():
 
 
 # ----------------------------------------------------------------------
+# the served path under every fault kind
+# ----------------------------------------------------------------------
+FAULT_CASES = {
+    # case: (placement on the trace, the engine's decode knobs)
+    "dropouts": (dict(dropout_ids=[0, 1, 2, 3]), dict(decode_mode="detect")),
+    "crashes": (dict(crash_ids=[0, 1]), dict(decode_mode="detect")),
+    "stragglers": (dict(straggler_ids=[0, 1]), dict(decode_mode="detect")),
+    "corrupt-detect": (
+        dict(corrupt_ids=[0]), dict(decode_mode="detect", verify_extras=1)
+    ),
+    "corrupt-correct": (
+        dict(corrupt_ids=[0]), dict(decode_mode="correct", error_budget=1)
+    ),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FAULT_CASES))
+def test_engine_serves_every_fault_kind(case):
+    """One fault kind placed on a deterministic trace, served through
+    the engine: every request decodes to the fixed-point oracle, the
+    fault lands where it was placed, and the replay's subsets and times
+    are those ``run_batch_over_pool`` reports for the same trace and
+    seed."""
+    faults, knobs = FAULT_CASES[case]
+    pool = CFG.n_workers + 4  # dropouts take every spare
+    trace = sample_trace(pool, Deterministic(1.0), seed=2)
+    trace = dataclasses.replace(
+        trace, uplink_delay=0.1 + 0.01 * np.arange(pool)
+    ).with_faults(**faults)
+    eng, w, rng = _engine(traces=[trace], admission=False, **knobs)
+    reqs = [eng.submit(rng.normal(size=(ROWS, K_DIM)) * mag, 0.0)
+            for mag in (0.1, 10.0)]
+    assert eng.run().replays == 1
+    for r in reqs:
+        assert r.state == DONE
+        assert np.array_equal(r.y, _exact_y(r.x, w))
+    served = eng.pipeline_result().replay_metrics[0]
+
+    plan = get_plan_for(
+        CFG.fit_to_pool(pool),
+        BlockShapes(k=K_DIM, ma=ROWS, mb=OUT, s=CFG.s, t=CFG.t),
+        field=FIELD,
+    )
+    a = np.stack([FIELD.encode(r.x.T, r.scale) for r in reqs])
+    b = np.stack([FIELD.encode(w, r.scale) for r in reqs])
+    ref = run_batch_over_pool(plan, a, b, trace, seed=0, **knobs).metrics
+    for f in ("phase2_ids", "responder_ids", "confirmed_by", "rejected_ids",
+              "corrected_workers"):
+        assert np.array_equal(getattr(served, f), getattr(ref, f)), f
+    assert served.completion_time == ref.completion_time
+
+    placed = np.concatenate([np.asarray(v) for v in faults.values()])
+    if case == "stragglers":
+        assert not np.isin(placed, served.phase2_ids).any()
+    elif case == "corrupt-detect":
+        assert served.rejected_ids.tolist() == [0]
+    elif case == "corrupt-correct":
+        assert served.corrected_workers.tolist() == [0]
+    else:  # dropped or crashed workers never answer
+        assert not np.isin(placed, served.responder_ids).any()
+        assert served.n_dropped + served.n_crashed == placed.size
+
+
+# ----------------------------------------------------------------------
 # observability: request lanes in the trace
 # ----------------------------------------------------------------------
 def test_serve_spans_link_queue_to_replay():
@@ -583,61 +642,3 @@ def test_prep_operands_same_for_host_and_device_input():
     a2, b2 = proto._prep_batched_operands(plan, jnp.asarray(a[0], jnp.int32), b[0])
     assert a2.shape == (1, 8, 4) and b2.shape == (1, 8, 4)
     assert np.array_equal(np.asarray(a2[0]), a[0] % p)
-
-
-# ----------------------------------------------------------------------
-# the async submission API under the engine
-# ----------------------------------------------------------------------
-def test_submit_handle_matches_sync_secure_matmul():
-    """handle.result() is exactly secure_matmul's answer: the field
-    computation is scale-deterministic, so the async path cannot drift."""
-    rng = np.random.default_rng(11)
-    a = rng.normal(size=(8, 6))
-    b = rng.normal(size=(8, 4))
-    h = secure_matmul_submit(a, b, s=2, t=2, z=1)
-    assert not h.done()
-    res = h.result()  # implicit flush
-    assert h.done()
-    want = secure_matmul(a, b, s=2, t=2, z=1)
-    assert np.array_equal(res.y, want.y)
-
-
-def test_executor_folds_submissions_into_one_flush():
-    """Same-signature submissions share one batched protocol run; the
-    per-request scales still decode each product exactly."""
-    ex = InlineExecutor(field=FIELD, seed=3)
-    rng = np.random.default_rng(12)
-    pairs = [
-        (rng.normal(size=(8, 6)) * mag, rng.normal(size=(8, 4)))
-        for mag in (0.1, 10.0)
-    ]
-    handles = [secure_matmul_submit(a, b, executor=ex) for a, b in pairs]
-    assert ex.pending() == 2 and ex.flushes == 0
-    ex.flush()
-    assert ex.flushes == 1 and ex.pending() == 0
-    for (a, b), h in zip(pairs, handles):
-        assert h.done()
-        assert np.array_equal(h.result().y, secure_matmul(a, b).y)
-    with pytest.raises(ValueError, match="field"):
-        secure_matmul_submit(
-            pairs[0][0], pairs[0][1], executor=ex,
-            field=Field(p=2**31 - 1),
-        )
-
-
-def test_private_linear_submit_path_matches_call():
-    """PrivateLinear with an executor: submit + flush + result is
-    bit-identical to the historical per-block protocol.run path."""
-    rng = np.random.default_rng(13)
-    w = rng.normal(size=(16, 6))
-    x = rng.normal(size=(4, 16))
-    plain = PrivateLinear(w, blocks=2, field=FIELD)(x)
-    ex = InlineExecutor(field=FIELD)
-    layer = PrivateLinear(w, blocks=2, field=FIELD, executor=ex)
-    h = layer.submit(x)
-    assert not h.done()
-    ex.flush()
-    assert h.done()
-    assert np.array_equal(h.result(), plain)
-    # the sync facade drives the same path
-    assert np.array_equal(layer(x), plain)

@@ -147,7 +147,7 @@ def _batched_i_evals(method, s, t, z, n_spare, batch, seed):
     folds them ([n_total, batch * bry, bcy]), and each product's Y."""
     import jax
 
-    from repro.runtime.scheduler import _batched_compute_closure
+    from repro.runtime.pipeline import _batched_compute_closure
 
     field = Field()
     rng = np.random.default_rng(seed)
@@ -174,7 +174,7 @@ def test_device_decode_matches_host_decode(case, n_spare, batch):
     ``Field.matmul`` decode's first t^2 coefficient rows bit for bit, and
     Y unfolds from them alone — for the prefix, the slowest tail and
     subsets that are neither."""
-    from repro.runtime.scheduler import _unfold_batched_y
+    from repro.runtime.pipeline import _unfold_batched_y
 
     plan, i_dev, want = _batched_i_evals(*case, n_spare, batch, seed=5 + n_spare)
     thr, t2 = plan.decode_threshold, plan.scheme.t ** 2
